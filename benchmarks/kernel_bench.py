@@ -1,4 +1,5 @@
-"""Kernel microbenchmarks (interpret mode on CPU — relative numbers only)
+"""Kernel microbenchmarks (interpret mode on the CPU backend — relative
+numbers only, never a device metric)
 + the §3.2 fusion-count analysis: on TPU, XLA fuses the paper's
 upcast-scale-softmax-downcast chain into ~1 fusion, so the exp-(7)
 pathology that made BPipe look good on GPT-3 cannot occur (DESIGN.md §3).
@@ -30,6 +31,8 @@ def fusion_count(f, *args) -> int:
 
 def main(print_csv=True, smoke=False):
     key = jax.random.PRNGKey(0)
+    interpret = jax.default_backend() == "cpu"
+    mode = f"interpret_mode={int(interpret)}"
     rows = []
     sm_shape = (1, 2, 64, 64) if smoke else (4, 8, 256, 256)
     seqs = (64,) if smoke else (128, 256)
@@ -39,11 +42,10 @@ def main(print_csv=True, smoke=False):
     t_unfused = _time(jax.jit(
         lambda x: ops.unfused_softmax_chain(x, 0.125, True)), x)
     t_pallas = _time(jax.jit(
-        lambda x: ops.fused_softmax(x, 0.125, True, 128, True)), x)
+        lambda x: ops.fused_softmax(x, 0.125, True, 128, interpret)), x)
     nf = fusion_count(lambda x: ops.unfused_softmax_chain(x, 0.125, True), x)
     rows.append(("softmax_xla_chain", t_unfused, f"xla_fusions={nf}"))
-    rows.append(("softmax_pallas_interpret", t_pallas,
-                 "interpret_mode=1"))
+    rows.append(("softmax_pallas_interpret", t_pallas, mode))
 
     # --- flash attention vs reference --------------------------------------
     for s in seqs:
@@ -54,9 +56,9 @@ def main(print_csv=True, smoke=False):
         t_ref = _time(jax.jit(lambda q, k, v: ref.flash_attention_ref(
             q, k, v, causal=True)), q, k, v)
         t_fa = _time(jax.jit(lambda q, k, v: ops.flash_attention(
-            q, k, v, True, 0, 0.0, None, blk, blk, True)), q, k, v)
+            q, k, v, True, 0, 0.0, None, blk, blk, interpret)), q, k, v)
         rows.append((f"flash_attn_ref_s{s}", t_ref, "jnp"))
-        rows.append((f"flash_attn_pallas_s{s}", t_fa, "interpret_mode=1"))
+        rows.append((f"flash_attn_pallas_s{s}", t_fa, mode))
 
     if print_csv:
         for name, us, derived in rows:

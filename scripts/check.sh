@@ -5,8 +5,8 @@
 #   scripts/check.sh -m 'not slow'   # extra pytest args pass through
 #
 # Needs no network and no PYTHONPATH fiddling (pyproject sets
-# pythonpath=["src"]); hypothesis is optional (tests/conftest.py falls
-# back to the deterministic stub in tests/_hypothesis_stub.py).
+# pythonpath=["src"]); the property tests need hypothesis
+# (requirements-dev.txt).
 #
 # Env knobs:
 #   REPRO_FUZZ_EXAMPLES       differential-harness simulator examples (200)
@@ -45,9 +45,9 @@ fi
 # Differential schedule-fuzz harness, seeded + bounded: random valid
 # ScheduleSpecs must keep the executor bit-identical to unmanaged
 # execution, the simulator above the ideal bound / engine-order
-# invariant, and executor bytes agreeing with the memory model. The
-# hypothesis stub draws from a fixed seed, so a red run reproduces; a
-# failing spec is written to fuzz_failures.json (CI uploads it).
+# invariant, and executor bytes agreeing with the memory model. A
+# failing spec is shrunk and written to fuzz_failures.json (CI uploads
+# it), so a red run reproduces from the JSON alone.
 rm -f fuzz_failures.json
 REPRO_FUZZ_EXAMPLES="${REPRO_FUZZ_EXAMPLES:-200}" \
 REPRO_FUZZ_EXEC_EXAMPLES="${REPRO_FUZZ_EXEC_EXAMPLES:-6}" \

@@ -6,5 +6,5 @@
   fused_softmax.py   — the §3.2 fused scale+mask+softmax chain (fwd+bwd)
 
 ops.py = jit-ready custom_vjp wrappers; ref.py = pure-jnp oracles that
-every kernel test asserts against (interpret=True on CPU).
+every kernel test asserts against (interpret mode on the CPU).
 """

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import ASSIGNED, INPUT_SHAPES, get_config, shape_applicable
 from repro.configs.base import TrainConfig
 from repro.core import flops as flops_mod
@@ -184,14 +183,14 @@ def lower_combo(cfg, shape, mesh, tcfg, cache_strategy="heads",
     t0 = time.time()
     jitted = (jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
               if out_sh is not None else jax.jit(fn, in_shardings=in_sh))
-    with compat.set_mesh(mesh):  # enables with_sharding_constraint(P(...))
+    with jax.set_mesh(mesh):  # enables with_sharding_constraint(P(...))
         lowered = jitted.lower(*args)
         t_lower = time.time() - t0
         t0 = time.time()
         compiled = lowered.compile()
     t_compile = time.time() - t0
     ma = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     txt = compiled.as_text()
     coll = rl.collective_bytes(txt)
     return {

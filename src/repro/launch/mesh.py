@@ -7,15 +7,13 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16x16 = 256 chips ("data", "model").
     Multi-pod: 2x16x16 = 512 chips ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 2, model: int = 4):
@@ -23,4 +21,9 @@ def make_host_mesh(data: int = 2, model: int = 4):
     XLA flag to have been set before jax init)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return compat.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config
 from repro.launch import roofline as rl
 from repro.launch.mesh import make_production_mesh
@@ -51,7 +50,7 @@ def run(arch: str, mesh_kind: str, bpipe: bool, *, p=16, B=128, s=2048,
              "labels": jax.ShapeDtypeStruct((B, s), jnp.int32)}
 
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(jax.grad(lossf)).lower(pshape, batch)
         compiled = lowered.compile()
     t_compile = time.time() - t0

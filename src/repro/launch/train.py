@@ -21,6 +21,7 @@ from repro.checkpoint import ckpt
 from repro.configs import get_config
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import DataConfig, make_batch
+from repro.launch.cache import enable_compile_cache
 from repro.optim import adam
 from repro.sharding import rules
 from repro.train.steps import init_all, make_train_step
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

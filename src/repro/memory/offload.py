@@ -10,30 +10,38 @@ NVLink/ICI, which is exactly the trade the simulator prices
 
 In the executor the copy is real: ``jax.vjp``'s returned function is a
 ``tree_util.Partial`` pytree whose leaves are the residual arrays, so
-``jax.device_put`` moves the whole stash to the host platform and back
-bit-identically (``to_host`` / ``to_device``).
+``jax.device_put`` moves the whole stash to its device's ``pinned_host``
+memory and back bit-identically (``to_host`` / ``to_device``). The stash
+never leaves its device's backend, and it returns to the device it came
+from.
 """
 from __future__ import annotations
 
 from typing import Any
 
+import jax
+
 from repro.core.schedule import FETCH, OFFLOAD
 from repro.memory import policy as respol
 
 
+def _to_memory(stash: Any, kind: str) -> Any:
+    def move(a):
+        if not isinstance(a, jax.Array):
+            return a
+        return jax.device_put(a, a.sharding.with_memory_kind(kind))
+    return jax.tree.map(move, stash)
+
+
 def to_host(stash: Any) -> Any:
-    """Move a stash (any pytree — including a vjp closure) to host
-    memory. Real ``jax.device_put`` onto the CPU platform; on a
-    CPU-only runtime this degenerates to a no-op copy, which keeps the
-    numerics contract (bit-identical round trip) testable anywhere."""
-    import jax
-    return jax.device_put(stash, jax.devices("cpu")[0])
+    """Move a stash (any pytree — including a vjp closure) to the pinned
+    host memory of the device each array lives on."""
+    return _to_memory(stash, "pinned_host")
 
 
 def to_device(stash: Any) -> Any:
-    """Move an offloaded stash back to the default accelerator."""
-    import jax
-    return jax.device_put(stash, jax.devices()[0])
+    """Move an offloaded stash back into its own device's memory."""
+    return _to_memory(stash, "device")
 
 
 HOST_OFFLOAD = respol.register(respol.ResidencyPolicy(

@@ -5,8 +5,8 @@ Three implementations, mirroring the paper's experimental arms (Table 3):
   * ``recompute`` — same math under jax.checkpoint (applied at the block
     level, see blocks.py) — the paper's "recompute" arm,
   * ``flash``     — the Pallas flash-attention kernel (paper's
-    "flash attn 2" arm). Used in kernel tests/benchmarks; dry-runs use
-    the reference path because Pallas on CPU is interpret-only.
+    "flash attn 2" arm). It compiles for the TPU; on the CPU backend,
+    where Pallas TPU kernels cannot compile, it runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -101,7 +101,8 @@ def _flash(q, k, v, cfg, *, causal, window, q_offset=0):
     from repro.kernels import ops  # lazy: kernels are optional at import
     return ops.flash_attention(
         q, k, v, causal=causal, window=window or 0,
-        softcap=cfg.attn_softcap, q_offset=q_offset, interpret=True)
+        softcap=cfg.attn_softcap, q_offset=q_offset,
+        interpret=jax.default_backend() == "cpu")
 
 
 def attention(p, x, cfg, positions, *, kind, impl=None, causal=True):

@@ -13,8 +13,8 @@ cotangent upstream.
 
 Residency policies (``repro.memory``, a ``ScheduleSpec`` dimension) give
 the stash other places to live: OFFLOAD/FETCH really ``jax.device_put``
-the vjp closure (a ``tree_util.Partial`` pytree) to the host platform
-and back; DROP frees the residuals keeping only the boundary input, and
+the vjp closure (a ``tree_util.Partial`` pytree) to its device's
+``pinned_host`` memory and back; DROP frees the residuals keeping only the boundary input, and
 RECOMPUTE re-runs the stage forward from it — both bit-identical to the
 resident execution, which ``tests/test_residency.py`` pins. Every move
 executes as its compiled ISSUE/WAIT halves (docs/transfer.md): the
@@ -434,7 +434,7 @@ class PipelineExecutor:
             if ins.is_wait:
                 return xfers.wait(chan(ins.op, i), ins.done_key)
             # real D2H: the vjp closure is a tree_util.Partial pytree, so
-            # device_put moves the residual arrays to the host platform
+            # device_put moves the residual arrays to pinned host memory
             return xfers.submit(
                 chan(ins.op, i), ins.done_key,
                 lambda: store.offload(i, ins.mb, ins.chunk, ins.sl,

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.core.bpipe import pair_adjacent_layout
 from repro.models.blocks import apply_layer, init_layer
@@ -148,8 +147,9 @@ def pipeline_loss_fn(cfg: ModelConfig, p: int, num_micro: int, *,
             [jnp.full((p - 1, mb, s), -1, labels.dtype), lbl_mb], 0)
 
         vaxes0 = (stage_axis,) + (tuple(data_axis) if data_axis else ())
-        state0 = compat.pvary(
-            jnp.zeros((mb, s, cfg.d_model), jnp.dtype(cfg.dtype)), vaxes0)
+        state0 = jax.lax.pcast(
+            jnp.zeros((mb, s, cfg.d_model), jnp.dtype(cfg.dtype)), vaxes0,
+            to="varying")
 
         def tick(state, xs):
             tok_t, lbl_t = xs
@@ -201,7 +201,7 @@ def make_spmd_train_loss(cfg: ModelConfig, mesh, p: int, num_micro: int,
              "final_norm": jax.tree.map(lambda _: P(), params["final_norm"])},
             {"tokens": P(data_axes), "labels": P(data_axes)},
         )
-        f = compat.shard_map(inner, mesh=mesh, in_specs=in_specs, out_specs=P())
+        f = jax.shard_map(inner, mesh=mesh, in_specs=in_specs, out_specs=P())
         return f(params, batch)
 
     return loss

@@ -8,16 +8,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Property tests import hypothesis; this offline container has no wheel for
-# it. Fall back to the deterministic stub (same API surface the suite uses)
-# so the suite still collects and runs; the real package wins when present.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_stub
-    _hypothesis_stub.install()
-
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)

@@ -1,9 +1,9 @@
 """Differential schedule-fuzz harness.
 
 Property-based over random *valid* ``ScheduleSpec``s — all kinds x
-residency x cap x v x overlap depth, drawn through the hypothesis
-strategies (the deterministic stub in ``_hypothesis_stub`` when the real
-package is absent, so failures reproduce run-to-run):
+residency x cap x v x overlap depth, drawn through hypothesis
+strategies (hypothesis replays a failing example from its example
+database, so failures reproduce run-to-run):
 
   (a) executor loss/grads are bit-identical to the unmanaged execution
       of the same schedule family (residency moves must never change

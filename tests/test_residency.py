@@ -291,6 +291,31 @@ def test_executor_residency_matches_loss_fn(exec_setup, residency):
     assert max(s.peak_local.values()) <= cap
 
 
+def test_offload_round_trip_stays_on_its_device():
+    """A host_offload stash (a vjp closure) lands in its own device's
+    pinned_host memory and comes back to that device bit-identical."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.memory import offload
+
+    x = jnp.linspace(-2.0, 2.0, 64, dtype=jnp.float32).reshape(8, 8)
+    out, vjp_fn = jax.vjp(jax.jit(lambda a: jnp.tanh(a @ a) * 3.0), x)
+    leaves = jax.tree.leaves(vjp_fn)
+    assert leaves
+    host = offload.to_host(vjp_fn)
+    for a, h in zip(leaves, jax.tree.leaves(host)):
+        assert h.sharding.memory_kind == "pinned_host"
+        assert h.sharding.device_set == a.sharding.device_set
+    back = offload.to_device(host)
+    for a, d in zip(leaves, jax.tree.leaves(back)):
+        assert d.sharding.memory_kind == a.sharding.memory_kind
+        assert d.sharding.device_set == a.sharding.device_set
+        assert np.array_equal(np.asarray(d), np.asarray(a))
+    ct = jnp.ones_like(out)
+    assert np.array_equal(np.asarray(back(ct)[0]), np.asarray(vjp_fn(ct)[0]))
+
+
 @pytest.mark.parametrize("kind,v,residency", [
     ("1f1b_interleaved", 2, "none"),
     ("bpipe_interleaved", 2, "none"),

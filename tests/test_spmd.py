@@ -14,7 +14,6 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import sys; sys.path.insert(0, %r)
     import dataclasses, jax, jax.numpy as jnp, numpy as np
-    from repro import compat
     from repro.configs import get_config
     from repro.pipeline.spmd import init_pipeline_params, make_spmd_train_loss
     from repro.models.blocks import apply_layer
@@ -23,7 +22,8 @@ SCRIPT = textwrap.dedent("""
     cfg = dataclasses.replace(get_config("qwen1.5-0.5b").reduced(),
                               num_layers=4, dtype="float32")
     p = 4
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(2, 4)
     params = init_pipeline_params(jax.random.PRNGKey(0), cfg, p)
     B, s, m = 8, 16, 4
     toks = jax.random.randint(jax.random.PRNGKey(3), (B, s+1), 0, cfg.vocab_size)
@@ -46,7 +46,7 @@ SCRIPT = textwrap.dedent("""
         nll = -jnp.take_along_axis(logp, jnp.maximum(lbl,0)[..., None], -1)[..., 0]
         return jnp.mean(nll)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for bpipe in (False, True):
             lossf = make_spmd_train_loss(cfg, mesh, p, num_micro=m, bpipe_stash=bpipe)
             loss = jax.jit(lossf)(params, batch)
@@ -131,11 +131,11 @@ REMAT_SCRIPT = textwrap.dedent("""
     import sys; sys.path.insert(0, %r)
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro import compat
     from repro.pipeline.spmd import _bpipe_perms, _remote_remat
 
     p = 4
-    mesh = compat.make_mesh((p,), ("model",))
+    mesh = jax.make_mesh((p,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     perm_out, perm_back = _bpipe_perms(p)
 
     def stage_fn(params, x):
@@ -153,8 +153,8 @@ REMAT_SCRIPT = textwrap.dedent("""
             y = fn(params, x)
             return jax.lax.psum(jnp.sum(y * y), "model")
         def outer(params, x):
-            f = compat.shard_map(inner, mesh=mesh,
-                                 in_specs=(P(), P("model")), out_specs=P())
+            f = jax.shard_map(inner, mesh=mesh,
+                              in_specs=(P(), P("model")), out_specs=P())
             return f(params, x)
         return outer
 
