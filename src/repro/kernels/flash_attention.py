@@ -213,6 +213,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
         ],
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="flash_fwd",
     )(qh, kh, vh)
     out = _seq_major_q(out, t["sq"])
     if return_lse:
@@ -329,6 +330,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
         scratch_shapes=[pltpu.VMEM((m * bq, hd), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*operands)
 
     # --- pass 2: dk/dv; grid (b, nkv, kv_blocks, q_blocks[arbitrary]) -------
@@ -345,6 +347,7 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
                         pltpu.VMEM((bk, hd), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*operands)
 
     return (_seq_major_q(dq, sq), dk[:, :, :sk].transpose(0, 2, 1, 3),

@@ -21,6 +21,7 @@ from repro.configs import get_config, list_configs
 from repro.core.notation import (A100_PEAK_BF16, NVLINK_BW,
                                  TPU_V5E_ICI_BW, TPU_V5E_PEAK_BF16,
                                  from_model)
+from repro.obs import export as obs_export
 from repro.planner import (SearchSpace, calibrate, cost_model_for,
                            plan_config, report)
 
@@ -143,7 +144,7 @@ def main(argv=None):
                          vocab_parallels=tuple(args.vocab_parallel), **kw)
 
     if args.trace:
-        events = calibrate.load_chrome_trace(args.trace)
+        events = obs_export.load_trace(args.trace)
         costs = calibrate.fit_trace(events, v=args.trace_v, b=args.trace_b,
                                     seq_chunks=args.trace_c)
         cost = calibrate.TraceCostModel(costs, peak_per_chip=CHIPS[args.chip],
@@ -193,7 +194,6 @@ def main(argv=None):
         from repro.core import plan as plan_mod
         from repro.core import simulator as SIM
         from repro.obs import Recorder
-        from repro.obs import export as obs_export
         from repro.obs import metrics as obs_metrics
         from repro.planner.rank import recommend, sim_config_for
         best = recommend(ranked, args.attention or None)

@@ -17,22 +17,27 @@ module is that vocabulary — and, by the repo invariant enforced in
   * ``Observer`` — the contract the engines call: ``dispatch`` fires on
     every instruction the ready-loop retires (engine order — what
     ``obs.compare`` audits for ordering divergence), ``span`` receives
-    every timed span, ``counter`` receives named counter samples.
-    ``Observer.emit(...)`` is the single span-construction helper the
-    simulator, executor, and transfer engine call — no other module
-    builds a ``Span``.
-  * ``Recorder`` — the collecting observer: spans + dispatch order +
-    counters, with the small derived views (makespan, per-stage order)
-    the metrics/timeline/export/compare layers build on.
+    every timed span. ``Observer.emit(...)`` is the single
+    span-construction helper the simulator, executor, and transfer
+    engine call — no other module builds a ``Span``.
+  * ``Recorder`` — the collecting observer: spans + dispatch order,
+    with the small derived views (makespan, per-stage order) the
+    metrics/timeline/export/compare layers build on.
+  * ``profile_span`` — the one place the program opens a JAX profiler
+    span (``pipeline.*`` in the executor): the same span identity, as
+    profiler args, on the profiler's clock, never blocking.
 
 Everything is zero-cost when no observer is attached: the engines guard
 every emission with ``if observer is not None`` and otherwise run the
-exact pre-instrumentation code path (golden-pinned).
+exact pre-instrumentation code path (golden-pinned). Profiler spans are
+always entered; they record only while a profiler session is active.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 #: Move phases, shared with the compiled-plan IR (``plan.ISSUE`` /
 #: ``plan.WAIT``): redeclared here (and asserted equal in tests) so the
@@ -140,29 +145,34 @@ def from_args(args: Mapping[str, Any], start: float, end: float) -> Span:
                 args.get("hbm"))
 
 
+def profile_span(name: str, **ids: Any) -> TraceAnnotation:
+    """A JAX profiler span (``TraceAnnotation``) named ``name`` whose
+    args are ``ids``: for an instruction the span identity (``op``,
+    ``stage``, ``mb``, ``chunk``, ``sl``, ``phase``) plus the emitter's
+    ``step`` counter. Nested spans are children by time containment on
+    the calling thread. Entering and leaving costs about a microsecond
+    or two with no profiler session active, and never blocks."""
+    return TraceAnnotation(name, **ids)
+
+
 class Observer:
     """The observer contract the engines speak.
 
     Subclass and override what you need; the base class swallows
     everything (attach-and-ignore is valid). The engines only ever call
-    these three callbacks plus ``emit``:
+    these two callbacks plus ``emit``:
 
       dispatch(stage, ins)        engine-order: the ready-loop retired
                                   one ``PlannedInstr`` (simulator and
                                   executor alike — ``obs.compare`` diffs
                                   these orders)
       span(span)                  one timed ``Span``
-      counter(name, stage, t, v)  a named counter sample
     """
 
     def dispatch(self, stage: int, ins: Any) -> None:  # noqa: ARG002
         pass
 
     def span(self, span: Span) -> None:  # noqa: ARG002
-        pass
-
-    def counter(self, name: str, stage: int, t: float,
-                value: float) -> None:  # noqa: ARG002
         pass
 
     # -- emission helper (the only Span construction call site) --------
@@ -188,7 +198,6 @@ class Recorder(Observer):
     def __init__(self) -> None:
         self.spans: List[Span] = []
         self.dispatches: List[DispatchRecord] = []
-        self.counters: Dict[Tuple[str, int], List[Tuple[float, float]]] = {}
 
     # -- observer callbacks --------------------------------------------
     def dispatch(self, stage: int, ins: Any) -> None:
@@ -199,10 +208,6 @@ class Recorder(Observer):
 
     def span(self, span: Span) -> None:
         self.spans.append(span)
-
-    def counter(self, name: str, stage: int, t: float,
-                value: float) -> None:
-        self.counters.setdefault((name, stage), []).append((t, value))
 
     # -- derived views --------------------------------------------------
     @property

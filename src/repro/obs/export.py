@@ -18,23 +18,17 @@ The round trip is lossless: every span's structured identity
 (op/stage/mb/chunk/sl/phase/track/channel/hbm) is written into the
 event's ``args`` and ``load_trace`` rebuilds the exact ``Span`` — no
 more re-parsing (and dropping) ``.sN``/``+w`` suffixes from name
-strings. Legacy traces saved by the old ``calibrate.chrome_trace``
-(no structured args) still load: the op string is split back into
-(op, sl, phase) by suffix.
+strings.
 """
 from __future__ import annotations
 
 import json
-import re
 from typing import Dict, Iterable, List, Mapping, Optional, Tuple
 
 from repro.obs import events as E
 
 #: Synthetic process ids grouping the track rows.
 PID_STAGES, PID_CHANNELS = 0, 1
-
-_LEGACY_WAIT = re.compile(r"\+w$")
-_LEGACY_SLICE = re.compile(r"\.s(\d+)")
 
 
 def _channel_tid(key: Tuple, index: Dict[Tuple, int]) -> int:
@@ -93,30 +87,9 @@ def save_trace(spans: Iterable[E.Span], path: str,
         json.dump(to_chrome(spans, counters), f)
 
 
-def _legacy_span(rec: dict, start: float, end: float) -> E.Span:
-    """Rebuild a span from a pre-obs trace record (the old
-    ``calibrate.chrome_trace`` format): structured fields live only in
-    the op string, so split the ``.sN`` / ``+w`` suffixes back out —
-    exactly the distinctions the old loader dropped."""
-    op = rec.get("cat") or rec.get("name", "")
-    phase = ""
-    if _LEGACY_WAIT.search(op):
-        op = _LEGACY_WAIT.sub("", op)
-        phase = E.WAIT
-    sl = 0
-    m = _LEGACY_SLICE.search(op)
-    if m:
-        sl = int(m.group(1))
-        op = _LEGACY_SLICE.sub("", op)
-    args = rec.get("args", {})
-    return E.make(op, rec.get("tid", 0), args.get("mb", 0),
-                  args.get("chunk", 0), sl, phase, start, end)
-
-
 def load_trace(path: str) -> List[E.Span]:
-    """Parse a saved trace back into ``Span``s — bit-exact for traces
-    this exporter wrote (structured args), best-effort suffix parsing
-    for legacy ``chrome_trace`` files."""
+    """Parse a trace this exporter wrote back into ``Span``s, bit-exact
+    (structured args)."""
     with open(path) as f:
         doc = json.load(f)
     spans: List[E.Span] = []
@@ -125,9 +98,5 @@ def load_trace(path: str) -> List[E.Span]:
             continue
         start = rec["ts"] / 1e6
         end = start + rec.get("dur", 0.0) / 1e6
-        args = rec.get("args", {})
-        if "op" in args:
-            spans.append(E.from_args(args, start, end))
-        else:
-            spans.append(_legacy_span(rec, start, end))
+        spans.append(E.from_args(rec["args"], start, end))
     return spans

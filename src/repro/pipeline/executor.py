@@ -42,6 +42,15 @@ KV-prefix gradients it emits onto the earlier slices' pending
 cotangents in a single pass. At seq_chunks=1 the engine is bit-identical
 to the unsliced path (pinned by tests/test_differential.py).
 
+Profiler spans (docs/observability.md "On the chip"): every step opens
+``pipeline.split``, one ``pipeline.<op>`` per executed instruction
+handler (args: the span identity plus the executor's step counter),
+``pipeline.grad_accum`` inside each B, and ``pipeline.merge``. They are
+always entered, never block, and record only while a JAX profiler
+session is active, so a device trace can put the host time of a step
+down to F, B, accumulation, moves, split/merge and the interpreter
+(the remainder).
+
 Compilation contract (tested): stage fns are built and jitted once in
 ``__init__`` and the microbatch is a ``jax.vjp`` *argument* — not a value
 closed over by a per-call lambda — so each virtual stage traces exactly
@@ -73,7 +82,7 @@ from repro.memory import policy as respol
 # legacy importers of the executor module.
 from repro.memory.store import ActivationStore, StoreStats, Unit
 from repro.models import blocks as blocks_mod
-from repro.obs.events import Observer, Recorder, Span
+from repro.obs.events import Observer, Recorder, Span, profile_span
 from repro.pipeline import stage as stage_mod
 from repro.transfer.channel import channel_key
 from repro.transfer.runtime import AsyncTransferRuntime
@@ -167,6 +176,7 @@ class PipelineExecutor:
                 for vs in range(self.n_virtual)]
         self.splitter = stage_mod.StageSplitter(cfg, self.n_virtual)
         self.notation = notation
+        self.steps = 0          # the ``step`` arg of the profiler spans
 
     # ------------------------------------------------------------------
     def _schedule_for(self, m: int) -> P.Schedule:
@@ -215,7 +225,10 @@ class PipelineExecutor:
             op for op, pol in {**respol.RELEASE_OPS,
                                **respol.RESTORE_OPS}.items() if pol.swap)
 
-        stage_params = self.splitter.split(params)
+        step_id = self.steps
+        self.steps += 1
+        with profile_span("pipeline.split", step=step_id):
+            stage_params = self.splitter.split(params)
         schedule = self._schedule_for(m)
         bounds = schedule.bounds
         partner = schedule.partner
@@ -295,23 +308,30 @@ class PipelineExecutor:
             """Concatenate earlier slices' retained KV (slice order =
             global position order), reading through ``store.peek`` so
             the prefix is reachable wherever a residency policy moved
-            the earlier units (partner store, host, dropped)."""
+            the earlier units (partner store, host, dropped). A
+            host-resident slice's KV is copied to the device for the
+            read; its stash stays where the policy put it."""
             if sl == 0:
                 return kv_zero[vs]
-            parts = [store.peek(i, mb, chunk, j)[-1] for j in range(sl)]
+            parts = [mem_offload.to_device(store.peek(i, mb, chunk, j)[-1])
+                     for j in range(sl)]
             return tuple(
                 (jnp.concatenate([part[li][0] for part in parts], axis=1),
                  jnp.concatenate([part[li][1] for part in parts], axis=1))
                 for li in range(len(kv_zero[vs])))
 
         def wrap(body):
-            """Shared post-instruction bookkeeping: span emission through
-            the attached observer (blocking so the span covers real
-            device time, not async dispatch) and the live stash-cap
-            assertion."""
+            """Shared post-instruction bookkeeping: the profiler span
+            around the handler body (a BLOCKED attempt is host time too),
+            span emission through the attached observer (blocking so the
+            span covers real device time, not async dispatch) and the
+            live stash-cap assertion."""
             def handler(i, ins):
                 t0 = time.perf_counter() if observer is not None else 0.0
-                sync = body(i, ins)
+                with profile_span("pipeline." + ins.op, op=ins.op, stage=i,
+                                  mb=ins.mb, chunk=ins.chunk, sl=ins.sl,
+                                  phase=ins.phase, step=step_id):
+                    sync = body(i, ins)
                 if sync is P.BLOCKED:
                     return P.BLOCKED
                 if observer is not None:
@@ -402,8 +422,9 @@ class PipelineExecutor:
                     prev = dkv_acc.get((vs, ins.mb, j))
                     dkv_acc[(vs, ins.mb, j)] = seg if prev is None \
                         else jax.tree.map(jnp.add, prev, seg)
-            grads[vs] = d_sp if grads[vs] is None else jax.tree.map(
-                jnp.add, grads[vs], d_sp)
+            with profile_span("pipeline.grad_accum", stage=i, step=step_id):
+                grads[vs] = d_sp if grads[vs] is None else jax.tree.map(
+                    jnp.add, grads[vs], d_sp)
             if vs > 0:
                 grad_in[(vs - 1, ins.mb, ins.sl)] = d_carry
             return (d_sp, d_carry)
@@ -493,8 +514,9 @@ class PipelineExecutor:
         P.run(schedule.streams, handlers, observer=observer, dep_gated=True)
         xfers.drain()                       # no copy escapes the step
 
-        loss = sum(losses.values()) * scale
-        full_grads = self.splitter.merge(grads)
+        with profile_span("pipeline.merge", step=step_id):
+            loss = sum(losses.values()) * scale
+            full_grads = self.splitter.merge(grads)
         stats = store.stats()
         stats.transfers_inflight_peak = xfers.inflight_peak
         return StepResult(loss=loss, grads=full_grads, stats=stats,

@@ -26,7 +26,6 @@ from repro.core import schedule
 from repro.core import simulator as SIM
 from repro.core.notation import Notation
 from repro.core.schedule import B, EVICT, F, LOAD
-from repro.obs import export as _export
 from repro.planner.rank import AnalyticCostModel, CostModel
 
 
@@ -148,19 +147,6 @@ class TraceCostModel(CostModel):
         arm = (self._factors[attention]
                / self._factors[self.traced_attention])
         return T0 * (b / b0) * (eff0 / eff) * arm
-
-
-# ---------------------------------------------------------------------------
-# Chrome trace round trip — aliases into the unified exporter
-# ---------------------------------------------------------------------------
-# The ad-hoc serializer that used to live here (which dropped the
-# WAIT-half ``+w`` and slice ``.sN`` distinctions on reload, mis-binning
-# move medians on replayed calibrations) is replaced by ``repro.obs.
-# export``: structured args round-trip every span field losslessly, and
-# the loader still parses old-format traces by suffix.
-chrome_trace = _export.to_chrome
-save_chrome_trace = _export.save_trace
-load_chrome_trace = _export.load_trace
 
 
 # ---------------------------------------------------------------------------

@@ -8,7 +8,7 @@ Three repo invariants live here (docs/observability.md):
     bit-identical to the pre-instrumentation engine,
   * one lossless trace format — every span field (WAIT ``+w`` halves,
     sequence slices ``.sN``, channel keys, HBM samples) survives the
-    Perfetto round trip, and legacy suffix-spelled traces still load,
+    Perfetto round trip,
   * one instruction census — the simulator and the real executor event
     streams of the SAME ScheduleSpec contain the same instruction set.
 """
@@ -171,26 +171,6 @@ def test_round_trip_keeps_wait_and_slice_fields(tmp_path):
     f2 = calibrate.fit_trace(back, v=1, seq_chunks=2)
     assert (f1.Tf, f1.Tb, f1.t_evict, f1.t_load) == pytest.approx(
         (f2.Tf, f2.Tb, f2.t_evict, f2.t_load))
-
-
-def test_loader_parses_legacy_suffix_traces(tmp_path):
-    """Pre-obs traces spelled slices/waits as name suffixes with no
-    structured args — the loader must still recover them."""
-    legacy = {"traceEvents": [
-        {"ph": "X", "pid": 0, "tid": 2, "name": "F0.s1", "cat": "F.s1",
-         "ts": 0.0, "dur": 1e6, "args": {"mb": 0}},
-        {"ph": "X", "pid": 0, "tid": 2, "name": "LOAD3+w", "cat": "LOAD+w",
-         "ts": 1.0e6, "dur": 0.5e6, "args": {"mb": 3}},
-        {"ph": "M", "pid": 0, "name": "thread_name"},
-    ]}
-    path = tmp_path / "legacy.json"
-    path.write_text(json.dumps(legacy))
-    back = OX.load_trace(str(path))
-    assert len(back) == 2
-    f, load = sorted(back, key=lambda s: s.start)
-    assert (f.op, f.sl, f.phase, f.stage) == (F, 1, "", 2)
-    assert f.duration == pytest.approx(1.0)
-    assert (load.op, load.phase, load.mb) == (LOAD, WAIT, 3)
 
 
 def test_chrome_events_carry_structured_args_and_counters():

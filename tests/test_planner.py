@@ -13,6 +13,7 @@ from repro.core import memory_model as MM
 from repro.core import schedule as S
 from repro.core import simulator as SIM
 from repro.core.notation import A100_HBM_BYTES, GPT3_96B, LLAMA_65B, Notation
+from repro.obs import export as OX
 from repro.planner import (AnalyticCostModel, SearchSpace, Table5CostModel,
                            calibrate, plan_config, recommend, report)
 from repro.planner import rank as R
@@ -242,9 +243,8 @@ def test_trace_calibration_changes_simulator_costs(tmp_path):
 
     # chrome-trace export round-trips losslessly enough to refit
     path = tmp_path / "step.trace.json"
-    calibrate.save_chrome_trace(events, str(path))
-    fit2 = calibrate.fit_trace(calibrate.load_chrome_trace(str(path)),
-                               v=1, b=1)
+    OX.save_trace(events, str(path))
+    fit2 = calibrate.fit_trace(OX.load_trace(str(path)), v=1, b=1)
     assert fit2.Tf == pytest.approx(fit.Tf, rel=1e-6)
     assert fit2.Tb == pytest.approx(fit.Tb, rel=1e-6)
 

@@ -60,8 +60,11 @@ def test_flash_attention_compiles_for_v5e(one_chip, nq, nkv, hd):
         return out, vjp(out)
 
     txt = jax.jit(fwd_bwd).lower(q, kv, kv).compile().as_text()
-    # forward, dq and dk/dv: three Pallas kernels, none left to XLA
+    # forward, dq and dk/dv: three Pallas kernels, none left to XLA,
+    # each under its own name in the compiled HLO
     assert txt.count("tpu_custom_call") >= 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in txt, name
 
 
 def test_full_width_stage_fits_v5e_hbm(one_chip):
