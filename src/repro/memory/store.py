@@ -56,6 +56,9 @@ class StoreStats:
     #                                    (executor transfer runtime; at most
     #                                    ScheduleSpec.depth — the slot is
     #                                    reserved before the copy starts)
+    grad_accum_calls: int = 0          # executor gradient-accumulator
+    #                                    calls in the step: Bs less one
+    #                                    per virtual stage
 
 
 class ActivationStore:

@@ -55,6 +55,9 @@ Compilation contract (tested): stage fns are built and jitted once in
 ``__init__`` and the microbatch is a ``jax.vjp`` *argument* — not a value
 closed over by a per-call lambda — so each virtual stage traces exactly
 once per activation shape and repeated ``step()`` calls recompile nothing.
+So is the gradient accumulator: one jitted call per B adds a whole stage
+gradient pytree into the donated running sum (the first B of a stage only
+stores), compiled once per virtual stage in the first step.
 
 Numerical contract (tested): for any schedule kind,
     executor.step(params, batch).loss == models.loss_fn(params, batch)
@@ -174,6 +177,13 @@ class PipelineExecutor:
                 jax.jit(stage_mod.make_stage_fn(
                     cfg, self.n_virtual, vs, remat))
                 for vs in range(self.n_virtual)]
+        # One accumulator for every virtual stage (it compiles once per
+        # stage gradient treedef): a whole stage's grads in one dispatch
+        # instead of one eager add per leaf. Donating the running sum
+        # lets XLA write into it, so a stage holds 2x its gradient bytes
+        # while adding, not 3x.
+        self.accumulate = jax.jit(
+            lambda acc, g: jax.tree.map(jnp.add, acc, g), donate_argnums=0)
         self.splitter = stage_mod.StageSplitter(cfg, self.n_virtual)
         self.notation = notation
         self.steps = 0          # the ``step`` arg of the profiler spans
@@ -274,6 +284,7 @@ class PipelineExecutor:
         grad_in: Dict[Tuple[int, int, int], Any] = {}
         losses: Dict[Tuple[int, int], jnp.ndarray] = {}
         grads: List[Any] = [None] * nv
+        accum_calls = 0
         dummy = (jnp.zeros((self.b, Ls, cfg.d_model), jnp.dtype(cfg.dtype)),
                  jnp.zeros((), jnp.float32))
         scale = jnp.float32(1.0 / m)
@@ -395,6 +406,7 @@ class PipelineExecutor:
             return primary
 
         def on_b(i, ins):
+            nonlocal accum_calls
             vs = ins.vs
             if vs == nv - 1:
                 cot = (scale / cnt[ins.mb], scale) if sliced else scale
@@ -423,8 +435,13 @@ class PipelineExecutor:
                     dkv_acc[(vs, ins.mb, j)] = seg if prev is None \
                         else jax.tree.map(jnp.add, prev, seg)
             with profile_span("pipeline.grad_accum", stage=i, step=step_id):
-                grads[vs] = d_sp if grads[vs] is None else jax.tree.map(
-                    jnp.add, grads[vs], d_sp)
+                if grads[vs] is None:
+                    grads[vs] = d_sp
+                else:
+                    # donates only the running sum: d_sp is returned below
+                    # and an observed step blocks on it
+                    grads[vs] = self.accumulate(grads[vs], d_sp)
+                    accum_calls += 1
             if vs > 0:
                 grad_in[(vs - 1, ins.mb, ins.sl)] = d_carry
             return (d_sp, d_carry)
@@ -519,6 +536,7 @@ class PipelineExecutor:
             full_grads = self.splitter.merge(grads)
         stats = store.stats()
         stats.transfers_inflight_peak = xfers.inflight_peak
+        stats.grad_accum_calls = accum_calls
         return StepResult(loss=loss, grads=full_grads, stats=stats,
                           events=list(recorder.spans)
                           if recorder is not None else None)
