@@ -65,8 +65,8 @@ def readings(cell, seed, devices, variants=True, log=print):
     opt, vocab = job["optimizer"], model["vocab_size"]
 
     def ref(b=batches, **kw):
-        return reference.train_readings(model, opt, b, key, devices=devices,
-                                        **kw)
+        return reference.train_readings(cell.arch, model, opt, b, key,
+                                        devices=devices, **kw)
     want = ref()
     out = {"program": check.gaps(prog, want)}
     if variants:

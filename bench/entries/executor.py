@@ -2,7 +2,8 @@
 followed by the jitted ``optim.adam.update``, every stage on the default
 device. This is the program's interpreter of a compiled ``plan.Schedule``:
 one ``jax.vjp`` per F, the stash in the activation store, EVICT/LOAD as
-store moves.
+store moves. The weights' layout is the cell's architecture module's
+(``to_program``, ``from_program``).
 """
 from __future__ import annotations
 
@@ -18,53 +19,18 @@ from repro.optim import adam
 from repro.pipeline import PipelineExecutor
 from repro.planner.rank import AnalyticCostModel
 
-#: bench layer leaf -> (program sub-tree, key) inside one stacked layer
-LAYER_KEYS = {"ln1_scale": ("norm1", "scale"), "ln2_scale": ("norm2", "scale"),
-              "wq": ("mixer", "wq"), "wk": ("mixer", "wk"),
-              "wv": ("mixer", "wv"), "wo": ("mixer", "wo"),
-              "bq": ("mixer", "bq"), "bk": ("mixer", "bk"),
-              "bv": ("mixer", "bv"), "wi": ("ffn", "wi"),
-              "wg": ("ffn", "wg"), "w2": ("ffn", "wo")}
-
-
-def to_program(t):
-    """The benchmark's layout -> ``models.model``'s (a re-keying)."""
-    layer = {}
-    for k, a in t["layers"].items():
-        sub, key = LAYER_KEYS[k]
-        layer.setdefault(sub, {})[key] = a
-    embed = {"table": t["embed"]}
-    if "unembed" in t:
-        embed["unembed"] = t["unembed"]
-    return {"embed": embed, "blocks": {"pos0": layer},
-            "final_norm": t["final_norm"]}
-
-
-def from_program(p):
-    """``models.model``'s layout -> the benchmark's."""
-    layer = {k: p["blocks"]["pos0"][sub][key]
-             for k, (sub, key) in LAYER_KEYS.items()
-             if key in p["blocks"]["pos0"].get(sub, {})}
-    out = {"embed": p["embed"]["table"], "layers": layer,
-           "final_norm": p["final_norm"]}
-    if "unembed" in p["embed"]:
-        out["unembed"] = p["embed"]["unembed"]
-    return out
-
 
 class Entry(TrainEntry):
-    def __init__(self, cfg, model, job, key, devices):
-        if cfg.block_pattern != ("attn",) or cfg.moe is not None:
-            raise ValueError("entry executor: uniform attention decoders only")
-        super().__init__(job, key, from_program)
+    def __init__(self, cfg, arch, model, job, key, devices):
+        super().__init__(job, key, lambda p: arch.from_program(p, cfg))
         sch = job["schedule"]
         self.cfg, self.job = cfg, job
         self.spec = ScheduleSpec(sch["kind"], sch["p"], sch["m"])
         self.ex = PipelineExecutor(cfg, spec=self.spec,
                                    micro_batch=job["micro_batch"],
                                    remat=job["remat"])
-        self.init = jax.jit(lambda k: to_program(
-            reference.init_params(model, k)))
+        self.init = jax.jit(lambda k: arch.to_program(
+            reference.init_params(arch, model, k), cfg))
         self.stats = None
 
     def start(self):

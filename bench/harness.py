@@ -3,7 +3,10 @@
 A cell is one entry of ``BENCHMARK.json``'s ``workloads``. Everything that
 belongs to it is found by name:
 
-  bench/configs/<file named in configs[].file>  the model's sizes
+  bench/configs/<file named in configs[].file>  the model's sizes, and
+                                 in ``reference`` its architecture
+  bench/models/<reference>.py    the architecture's reference, weight
+                                 layout and FLOP count
   bench/traffic/<traffic>.json   the job: batch, sequence, schedule,
                                  optimizer, and which entry runs it
   bench/entries/<entry>.py       the timed path (class ``Entry``)
@@ -31,6 +34,8 @@ import pathlib
 import shutil
 import tempfile
 import time
+import types
+import typing
 from typing import Callable, Dict, List, Optional
 
 import jax
@@ -59,6 +64,7 @@ class Cell:
     limits: Dict        # name -> limit of each compared number
     per_layer: List[Dict]
     end_to_end: List[Dict]
+    arch: types.ModuleType  # the configuration's ``bench/models`` module
 
 
 def find_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
@@ -72,23 +78,44 @@ def find_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     def mine(ms):
         return [m for m in ms if name in m.get("workloads", [name])]
 
-    return Cell(name=name, chips=w["chips"],
-                config=load_json(root / cfg["file"]),
+    config = load_json(root / cfg["file"])
+    return Cell(name=name, chips=w["chips"], config=config,
                 job=load_json(root / "bench" / "traffic" / f"{w['traffic']}.json"),
                 limits=load_json(root / "bench" / "limits" / f"{name}.json")["limits"],
                 per_layer=mine(bench["per_layer"]),
-                end_to_end=mine(bench["end_to_end"]))
+                end_to_end=mine(bench["end_to_end"]),
+                arch=load_arch(config["reference"]))
+
+
+def load_arch(name: str) -> types.ModuleType:
+    return importlib.import_module(f"bench.models.{name}")
 
 
 def model_config(config: Dict, job: Dict):
     """The program's ``ModelConfig``: the registered one with the
-    configuration file's sizes and the job's attention implementation."""
+    configuration file's sizes and the job's attention implementation. A
+    nested block (``moe``) replaces the named keys of the registered
+    block, or builds the field's own type where that is None."""
     from repro.configs import get_config
     base = get_config(config["base"])
     fields = {f.name for f in dataclasses.fields(base)}
-    kw = {k: tuple(v) if isinstance(v, list) else v
+    kw = {k: _field_value(base, k, v)
           for k, v in config["model"].items() if k in fields}
     return dataclasses.replace(base, attn_impl=job["attn_impl"], **kw)
+
+
+def _field_value(base, name: str, v):
+    if isinstance(v, list):
+        return tuple(v)
+    if not isinstance(v, dict):
+        return v
+    registered = getattr(base, name)
+    if registered is not None:
+        return dataclasses.replace(registered, **v)
+    hint = typing.get_type_hints(type(base))[name]
+    (cls,) = [t for t in typing.get_args(hint) or (hint,)
+              if dataclasses.is_dataclass(t)]
+    return cls(**v)
 
 
 def _load_file(kind: str, name: str):
@@ -165,7 +192,8 @@ def start(cell: Cell, seed: int, devices, log: Callable[[str], None] = print,
         f"{cfg.tie_embeddings} "
         f"attn {cfg.attn_impl}; batch {rows} x {seq} tokens, job "
         f"{json.dumps({k: job[k] for k in ('entry', 'schedule', 'micro_batch', 'remat')})}")
-    entry = load_entry(job["entry"]).Entry(cfg, model, job, key, devices)
+    entry = load_entry(job["entry"]).Entry(cfg, cell.arch, model, job, key,
+                                           devices)
     entry.start()
     losses = []
     for step in range(job["check_steps"]):
@@ -175,9 +203,10 @@ def start(cell: Cell, seed: int, devices, log: Callable[[str], None] = print,
         if step == 0:
             first = entry.first_grad_norms()
     change = entry.change_norms()
+    kinds = cell.arch.layer_kinds(model)
     prog = {"loss": [float(x) for x in losses],
-            "grad": reference.per_leaf(first),
-            "change": reference.per_leaf(change)}
+            "grad": reference.per_leaf(first, kinds),
+            "change": reference.per_leaf(change, kinds)}
     for line in entry.info():
         log(line)
     if peaks is not None and hasattr(entry, "planner_step_s"):
@@ -187,6 +216,18 @@ def start(cell: Cell, seed: int, devices, log: Callable[[str], None] = print,
     return entry, prog, batch_at, cfg
 
 
+def metric_ctx(cell: Cell, cfg, tr, lo: int, hi: int, ids, steps: int,
+               peaks: Optional[Dict]) -> Dict:
+    """What a ``bench/metrics`` reducer reads: the trace and its window,
+    the chips' ids, the steps traced, the program's config, the job, the
+    chip's peaks, the architecture module (``model``) and the
+    configuration's sizes (``sizes``)."""
+    return {"trace": tr, "lo": lo, "hi": hi, "devices": ids, "steps": steps,
+            "cfg": cfg, "job": cell.job, "peaks": peaks, "flops": flops,
+            "trace_mod": trace_mod, "model": cell.arch,
+            "sizes": cell.config["model"]}
+
+
 def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
         t0: float, devices, log: Callable[[str], None] = print,
         peaks: Optional[Dict] = None) -> Dict:
@@ -194,6 +235,11 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
     job, model = cell.job, cell.config["model"]
     tokens_per_step = job["rows"] * job["seq"]
     entry, prog, batch_at, cfg = start(cell, seed, devices, log, peaks)
+    # What set-up made (executables, traces, caches) lives through the
+    # window: keep it out of the window's full collections, whose count
+    # and length otherwise vary from run to run of the host-bound step.
+    gc.collect()
+    gc.freeze()
     setup_s = time.perf_counter() - t0
     log(f"set-up: {setup_s:.3f} s; first losses {prog['loss']}")
 
@@ -253,9 +299,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
         busy = [trace_mod.length(trace_mod.busy(tr, i, lo, hi)) / 1e9
                 for i in ids]
         device.update(busy_s=sum(busy) / len(busy), window_s=(hi - lo) / 1e9)
-        ctx = {"trace": tr, "lo": lo, "hi": hi, "devices": ids,
-               "steps": n_steps, "cfg": cfg, "job": job, "peaks": peaks,
-               "flops": flops, "trace_mod": trace_mod}
+        ctx = metric_ctx(cell, cfg, tr, lo, hi, ids, n_steps, peaks)
         result["metrics"] = {}
         for m in cell.per_layer:
             v = _load_file("metrics", m["name"]).read(ctx)
@@ -272,12 +316,14 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
         log(f"idle seconds of chip {ids[0]} by host span: {totals}")
     entry.finish()
     del entry, window_losses
+    gc.unfreeze()
     gc.collect()
 
     # --- the check, after the program's state is freed
     t_ref = time.perf_counter()
     ref = reference.train_readings(
-        model, job["optimizer"], [batch_at(s) for s in range(job["check_steps"])],
+        cell.arch, model, job["optimizer"],
+        [batch_at(s) for s in range(job["check_steps"])],
         reference.seed_key(seed), devices=devices)
     numbers = check.gaps(prog, ref)
     numbers["window_compiles"] = counter.lowered + counter.compiled

@@ -88,9 +88,7 @@ def split(cell, seed, steps, devices, log=print, peaks=None):
     (win,) = tr.spans("window")
     lo, hi = win.start, win.end
     ids = [d.id for d in devices]
-    ctx = {"trace": tr, "lo": lo, "hi": hi, "devices": ids, "steps": steps,
-           "cfg": cfg, "job": cell.job, "peaks": peaks, "flops": harness.flops,
-           "trace_mod": tm}
+    ctx = harness.metric_ctx(cell, cfg, tr, lo, hi, ids, steps, peaks)
     metrics = {m["name"]: harness._load_file("metrics", m["name"]).read(ctx)
                for m in cell.per_layer}
     metrics.update({name: r.read(ctx) for name, r in reducers.items()})

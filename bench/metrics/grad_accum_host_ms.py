@@ -1,6 +1,6 @@
 """grad_accum_host_ms (ms): host time per step in the program's
 ``pipeline.grad_accum`` spans: adding each B's stage gradients to the
-step's running sum, one eager ``jnp.add`` per leaf."""
+step's running sum, one jitted call per B that donates the sum."""
 
 SPANS = ("pipeline.grad_accum",)
 

@@ -1,16 +1,13 @@
 """Plain float32 reference of the benchmark's decoder models, and the
-benchmark's own weights.
+benchmark's own weights, for any architecture of ``bench/models/``.
 
 Nothing here imports the program. The weights are made from the seed by
-``init_params`` in this module's own layout (layers stacked on a leading
-axis); an entry re-keys them into the program's layout, and the reference
-makes its own copy from the same seed. The model is the published Qwen1.5
-(Hugging Face's Qwen2) decoder as the configuration file's ``model`` block
-sizes it: pre-norm layers with RMSNorm, multi-head or grouped attention
-with optional q/k/v bias and rotate-half rotary positions, a SwiGLU MLP,
-unscaled token embeddings, a tied or separate output head, and next-token
-cross-entropy averaged over every token. Adam decays the weight matrices
-(``DECAYED``) and no norm scale or bias.
+``init_params`` in the architecture module's own layout (layers stacked
+on a leading axis); an entry re-keys them into the program's layout, and
+the reference makes its own copy from the same seed. The module gives the
+layers, the head's loss and the leaves Adam decays; this file trains them
+layer by layer (``LayerwiseTrainer``) and reads what ``bench/check.py``
+compares.
 
 Every matrix product runs at ``Precision.HIGHEST``. ``quant`` replaces the
 operands of every product by their value rounded to a lower precision
@@ -19,18 +16,15 @@ through the rounding.
 """
 from __future__ import annotations
 
+import itertools
 import math
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-NORM_EPS = 1e-6
-#: the leaves weight decay applies to: every weight matrix, no norm or bias
-DECAYED = frozenset({"embed", "unembed", "wq", "wk", "wv", "wo", "wi", "wg",
-                     "w2"})
 
 
 def seed_key(seed: int):
@@ -42,42 +36,14 @@ def seed_key(seed: int):
 
 
 # ---------------------------------------------------------------------------
-# Weights
+# Weights and the layers' stacks
 # ---------------------------------------------------------------------------
-def param_shapes(m: Dict) -> Dict:
-    """Leaf shapes of the layout, from the configuration's ``model``."""
-    L, D, F, V = m["num_layers"], m["d_model"], m["d_ff"], m["vocab_size"]
-    nq, nkv, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    lay = {"ln1_scale": (L, D), "wq": (L, D, nq, hd), "wk": (L, D, nkv, hd),
-           "wv": (L, D, nkv, hd), "wo": (L, nq, hd, D),
-           "ln2_scale": (L, D), "wi": (L, D, F), "wg": (L, D, F),
-           "w2": (L, F, D)}
-    if m["qkv_bias"]:
-        lay.update(bq=(L, nq, hd), bk=(L, nkv, hd), bv=(L, nkv, hd))
-    out = {"embed": (V, D), "final_norm": {"scale": (D,)}, "layers": lay}
-    if not m["tie_embeddings"]:
-        out["unembed"] = (D, V)
-    return out
-
-
-def _fan_in(name: str, shape) -> Optional[int]:
-    """Fan-in of a weight (its init scale is 1/sqrt(fan_in)); None for
-    norms and biases."""
-    if name in ("embed", "unembed"):
-        return shape[-1] if name == "embed" else shape[0]
-    if name in ("wq", "wk", "wv", "wi", "wg"):
-        return shape[1]
-    if name == "wo":
-        return shape[1] * shape[2]
-    if name == "w2":
-        return shape[1]
-    return None
-
-
-def init_params(m: Dict, key) -> Dict:
-    """Seeded float32 weights. Weights ~ N(0, 1/fan_in); biases
-    ~ N(0, 0.02^2); norm scales ~ 1 + N(0, 0.02^2)."""
-    shapes = param_shapes(m)
+def init_params(arch, m: Dict, key) -> Dict:
+    """Seeded float32 weights in ``arch``'s layout. Weights
+    ~ N(0, 1/fan_in); biases ~ N(0, 0.02^2); norm scales
+    ~ 1 + N(0, 0.02^2). Leaf i of the flattened shapes draws from
+    ``fold_in(key, i)``."""
+    shapes = arch.param_shapes(m)
     flat, tree = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     leaves = []
@@ -85,7 +51,7 @@ def init_params(m: Dict, key) -> Dict:
         name = _name(path)
         k = jax.random.fold_in(key, i)
         z = jax.random.normal(k, shape, jnp.float32)
-        fan = _fan_in(name, shape)
+        fan = arch.fan_in(name, shape)
         if fan is not None:
             leaves.append(z / math.sqrt(fan))
         elif name.endswith("scale"):
@@ -100,8 +66,30 @@ def _name(path) -> str:
     return jax.tree_util.keystr(path[-1:]).strip("[]'")
 
 
+def layer_rows(kinds: Sequence[str]) -> Dict[str, List[int]]:
+    """The layer index of each row of each kind's stack."""
+    rows: Dict[str, List[int]] = {}
+    for i, kind in enumerate(kinds):
+        rows.setdefault(kind, []).append(i)
+    return rows
+
+
+def split_layers(params: Dict, kinds: Sequence[str]):
+    """``(head, [layer 0's leaves, layer 1's, ...])``: the head leaves, and
+    each layer's row of its kind's stack (``layers`` itself where every
+    layer is of one kind, ``layers[kind]`` otherwise)."""
+    head = {k: v for k, v in params.items() if k != "layers"}
+    rows = layer_rows(kinds)
+    stacks = params["layers"] if len(rows) > 1 else {kinds[0]: params["layers"]}
+    layers = [None] * len(kinds)
+    for kind, idx in rows.items():
+        for j, i in enumerate(idx):
+            layers[i] = jax.tree.map(lambda a: a[j], stacks[kind])
+    return head, layers
+
+
 def leaf_norms(tree: Dict) -> Dict[str, jnp.ndarray]:
-    """L2 norm of every leaf, a stacked layer leaf split per layer:
+    """L2 norm of every leaf, a stacked layer leaf split per row:
     ``{"embed": (), "layers.wq": (L,), ...}``."""
     out = {}
     for path, a in jax.tree_util.tree_flatten_with_path(tree)[0]:
@@ -115,21 +103,25 @@ def leaf_norms(tree: Dict) -> Dict[str, jnp.ndarray]:
     return out
 
 
-def per_leaf(norms: Dict) -> Dict[str, float]:
-    """Host floats of ``leaf_norms``, per layer: ``layers.3.wq``."""
+def per_leaf(norms: Dict, kinds: Sequence[str]) -> Dict[str, float]:
+    """Host floats of ``leaf_norms``, per layer: ``layers.3.wq``, with 3
+    the layer's index in the model whatever its kind."""
+    rows = layer_rows(kinds)
     out = {}
     for name, v in norms.items():
         v = np.asarray(v, np.float64)
-        if name.startswith("layers."):
-            for i, x in enumerate(v):
-                out[f"layers.{i}.{name[len('layers.'):]}"] = float(x)
-        else:
+        if not name.startswith("layers."):
             out[name] = float(v)
+            continue
+        rest = name[len("layers."):]
+        kind, leaf = rest.split(".", 1) if len(rows) > 1 else (kinds[0], rest)
+        for i, x in zip(rows[kind], v):
+            out[f"layers.{i}.{leaf}"] = float(x)
     return out
 
 
 # ---------------------------------------------------------------------------
-# Forward and loss
+# Products and the whole model's loss
 # ---------------------------------------------------------------------------
 def fp8_round(x):
     """Per-tensor scaled float8 (e4m3) rounding, gradient straight
@@ -142,66 +134,32 @@ def fp8_round(x):
 QUANT = {"fp8": fp8_round}
 
 
-def _mm(eq, a, b, quant):
+def mm(eq, a, b, quant):
     if quant is not None:
         a, b = quant(a), quant(b)
     return jnp.einsum(eq, a, b, precision=HIGHEST)
 
 
-def _norm(x, scale):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                             + NORM_EPS) * scale
+def loss(arch, params, tokens, labels, m: Dict,
+         quant: Optional[Callable] = None):
+    """The rows' loss, the whole model at once: the head's mean
+    cross-entropy plus every layer's ``aux``. Each run of consecutive
+    layers of one kind is one ``lax.scan``."""
+    kinds = arch.layer_kinds(m)
+    head, layers = split_layers(params, kinds)
+    carry = (arch.embed(head, tokens), jnp.zeros((), jnp.float32))
+    i = 0
+    for kind, run in itertools.groupby(kinds):
+        n = len(list(run))
 
-
-def _rope(x, theta):
-    s, hd = x.shape[1], x.shape[-1]
-    half = hd // 2
-    freq = theta ** (-np.arange(half, dtype=np.float64) / half)
-    ang = np.arange(s, dtype=np.float64)[:, None] * freq[None]
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(m, quant, x, lp):
-    b, s, _ = x.shape
-    nq, nkv, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
-    h = _norm(x, lp["ln1_scale"])
-    q = _mm("bsd,dnh->bsnh", h, lp["wq"], quant)
-    k = _mm("bsd,dnh->bsnh", h, lp["wk"], quant)
-    v = _mm("bsd,dnh->bsnh", h, lp["wv"], quant)
-    if "bq" in lp:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q, k = _rope(q, m["rope_theta"]), _rope(k, m["rope_theta"])
-    g = nq // nkv
-    q = q.reshape(b, s, nkv, g, hd)
-    sc = _mm("bqngh,bknh->bngqk", q, k, quant) / math.sqrt(hd)
-    causal = np.tril(np.ones((s, s), bool))
-    sc = jnp.where(causal, sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = _mm("bngqk,bknh->bqngh", p, v, quant).reshape(b, s, nq, hd)
-    x = x + _mm("bsnh,nhd->bsd", o, lp["wo"], quant)
-    h = _norm(x, lp["ln2_scale"])
-    u = jax.nn.silu(_mm("bsd,df->bsf", h, lp["wi"], quant)) \
-        * _mm("bsd,df->bsf", h, lp["wg"], quant)
-    return x + _mm("bsf,fd->bsd", u, lp["w2"], quant), None
-
-
-def loss(params, tokens, labels, m: Dict, quant: Optional[Callable] = None):
-    """Mean next-token cross-entropy over every token of the rows."""
-    x = params["embed"][tokens]
-    x, _ = jax.lax.scan(lambda c, lp: _layer(m, quant, c, lp), x,
-                        params["layers"])
-    fn = params["final_norm"]
-    x = _norm(x, fn["scale"])
-    if "unembed" in params:
-        logits = _mm("bsd,dv->bsv", x, params["unembed"], quant)
-    else:
-        logits = _mm("bsd,vd->bsv", x, params["embed"], quant)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    return jnp.mean(lse - picked)
+        def body(c, lp, kind=kind):
+            x, a = arch.layer(m, kind, quant, lp, c[0])
+            return (x, c[1] + sum(jax.tree.leaves(a))), None
+        carry, _ = jax.lax.scan(body, carry, jax.tree.map(
+            lambda *a: jnp.stack(a), *layers[i:i + n]))
+        i += n
+    x, aux = carry
+    return arch.head_loss(m, quant, head, x, labels) + aux
 
 
 # ---------------------------------------------------------------------------
@@ -224,42 +182,42 @@ class LayerwiseTrainer:
     """Trains the reference one layer at a time: layer i's weights, Adam
     state and gradients live on ``devices[i * len(devices) // L]``, the
     embedding, final norm and head on ``devices[0]``. Forward keeps each
-    layer's input; backward recomputes one layer at a time from it. So the
-    reference of a model that fills several chips fits beside nothing
-    else, and a model on one chip trains with its memory in blocks."""
+    layer's input; backward recomputes one layer at a time from it, with
+    one forward and one backward jitted per kind of layer. So the reference
+    of a model that fills several chips fits beside nothing else, and a
+    model on one chip trains with its memory in blocks.
 
-    def __init__(self, m: Dict, opt: Dict, key, devices, quant=None,
-                 decays: Callable[[str], bool] = DECAYED.__contains__):
+    Each row is trained as a micro-batch of its own: a layer's ``aux``
+    enters the loss of the row it was computed over."""
+
+    def __init__(self, arch, m: Dict, opt: Dict, key, devices, quant=None,
+                 decays: Optional[Callable[[str], bool]] = None):
         self.m, self.opt = m, opt
+        decays = decays or arch.DECAYED.__contains__
         q = QUANT[quant] if quant else None
-        L = m["num_layers"]
+        self.kinds = tuple(arch.layer_kinds(m))
+        L = len(self.kinds)
         self.devs = [devices[i * len(devices) // L] for i in range(L)]
         self.head_dev = devices[0]
-        self.init = jax.jit(lambda k: init_params(m, k))
+        self.init = jax.jit(lambda k: init_params(arch, m, k))
 
         def head_loss(head, x, labels):
-            fn = head["final_norm"]
-            x = _norm(x, fn["scale"])
-            if "unembed" in head:
-                logits = _mm("bsd,dv->bsv", x, head["unembed"], q)
-            else:
-                logits = _mm("bsd,vd->bsv", x, head["embed"], q)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-            return jnp.mean(lse - picked)
+            return arch.head_loss(m, q, head, x, labels)
 
-        def embed(head, tokens):
-            return head["embed"][tokens]
+        def bwd(layer):
+            def f(lp, x, g):
+                (_, aux), vjp_fn = jax.vjp(layer, lp, x)
+                return vjp_fn((g, jax.tree.map(jnp.ones_like, aux)))
+            return jax.jit(f)
 
-        def layer(lp, x):
-            return _layer(m, q, x, lp)[0]
-
-        self.embed = jax.jit(embed)
-        self.layer_fwd = jax.jit(layer)
-        self.layer_bwd = jax.jit(lambda lp, x, g: jax.vjp(layer, lp, x)[1](g))
+        layers = {k: (lambda lp, x, k=k: arch.layer(m, k, q, lp, x))
+                  for k in set(self.kinds)}
+        self.embed = jax.jit(arch.embed)
+        self.layer_fwd = {k: jax.jit(f) for k, f in layers.items()}
+        self.layer_bwd = {k: bwd(f) for k, f in layers.items()}
         self.head_vg = jax.jit(jax.value_and_grad(head_loss, argnums=(0, 1)))
         self.embed_bwd = jax.jit(lambda head, t, g: jax.vjp(
-            lambda h: embed(h, t), head)[1](g)[0])
+            lambda h: arch.embed(h, t), head)[1](g)[0])
         self.add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
                            donate_argnums=0)
         self.sq = jax.jit(lambda t: sum(jnp.sum(jnp.square(a))
@@ -284,30 +242,29 @@ class LayerwiseTrainer:
         self.key = key
 
     def _split(self, params):
-        head = {k: v for k, v in params.items() if k != "layers"}
+        head, layers = split_layers(params, self.kinds)
         head = jax.device_put(head, self.head_dev)
-        layers = [jax.device_put(jax.tree.map(lambda a: a[i],
-                                              params["layers"]), d)
-                  for i, d in enumerate(self.devs)]
+        layers = [jax.device_put(lp, d) for lp, d in zip(layers, self.devs)]
         return head, layers
 
     def _grads(self, head, layers, tokens, labels):
         """Loss and gradients of one block of rows."""
         put = jax.device_put
         tokens = put(tokens, self.head_dev)
-        x, xs = self.embed(head, tokens), []
-        for lp, d in zip(layers, self.devs):
+        x, xs, auxes = self.embed(head, tokens), [], []
+        for kind, lp, d in zip(self.kinds, layers, self.devs):
             xs.append(put(x, d))            # each layer's input, kept
-            x = self.layer_fwd(lp, xs[-1])
+            x, aux = self.layer_fwd[kind](lp, xs[-1])
+            auxes += jax.tree.leaves(aux)
         loss, (g_head, g) = self.head_vg(head, put(x, self.head_dev),
                                          put(labels, self.head_dev))
         g_layers = [None] * len(layers)
         for i in reversed(range(len(layers))):
-            g_layers[i], g = self.layer_bwd(layers[i], xs[i],
-                                            put(g, self.devs[i]))
+            g_layers[i], g = self.layer_bwd[self.kinds[i]](
+                layers[i], xs[i], put(g, self.devs[i]))
         g_head = self.add(g_head, self.embed_bwd(head, tokens,
                                                  put(g, self.head_dev)))
-        return loss, g_head, g_layers
+        return float(loss) + sum(float(a) for a in auxes), g_head, g_layers
 
     def readings(self, batches) -> Dict:
         """Train one update per batch, a row at a time, and return what
@@ -331,7 +288,7 @@ class LayerwiseTrainer:
                 grads = [gh] + gl
                 acc = grads if acc is None else [
                     self.add(a, g) for a, g in zip(acc, grads)]
-                total += float(lv)
+                total += lv
             losses.append(total / rows)
             gn = math.sqrt(sum(float(self.sq(g)) for g in acc)) / rows
             scale = min(1.0, opt["grad_clip"] / (gn + 1e-9)) / rows
@@ -354,16 +311,16 @@ class LayerwiseTrainer:
         return {"loss": losses, "grad": first, "change": self._per_leaf(diff)}
 
     def _per_leaf(self, groups) -> Dict[str, float]:
-        out = per_leaf(self.norms(groups[0]))
+        out = per_leaf(self.norms(groups[0]), self.kinds)
         for i, g in enumerate(groups[1:]):
             for k, v in self.norms(g).items():
                 out[f"layers.{i}.{k}"] = float(v)
         return out
 
 
-def train_readings(m: Dict, opt: Dict, batches, key, quant: Optional[str] = None,
-                   devices=None, **kw) -> Dict:
+def train_readings(arch, m: Dict, opt: Dict, batches, key,
+                   quant: Optional[str] = None, devices=None, **kw) -> Dict:
     """The reference's readings of ``len(batches)`` steps from the seed's
     weights (see ``LayerwiseTrainer.readings``)."""
-    return LayerwiseTrainer(m, opt, key, devices or jax.devices()[:1],
+    return LayerwiseTrainer(arch, m, opt, key, devices or jax.devices()[:1],
                             quant, **kw).readings(batches)

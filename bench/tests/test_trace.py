@@ -8,24 +8,21 @@ import types
 import pytest
 
 from bench import flops, harness, trace as tm
+from bench.models import qwen2
 from bench.trace import Event, Trace
 
 DATA = pathlib.Path(__file__).resolve().parent / "data" / "tiny.xplane.pb"
 MS = 1e6  # ns
+SIZES = {"num_layers": 2, "num_heads": 4, "num_kv_heads": 4, "head_dim": 16,
+         "d_model": 64, "d_ff": 128, "vocab_size": 512}
 
 
 def _ctx(tr, lo, hi, steps=1, devices=(0,)):
     return {"trace": tr, "lo": lo, "hi": hi, "devices": list(devices),
             "steps": steps, "trace_mod": tm, "flops": flops,
             "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11},
-            "cfg": types.SimpleNamespace(num_layers=2, num_heads=4,
-                                         num_kv_heads=4, head_dim=16,
-                                         d_model=64, d_ff=128,
-                                         vocab_size=512, moe=None,
-                                         encoder_layers=0, mlp_kind="swiglu",
-                                         window_size=0,
-                                         layer_kinds=lambda: ("attn",) * 2),
-            "job": {"rows": 2, "seq": 128, "micro_batch": 1}}
+            "cfg": types.SimpleNamespace(**SIZES), "model": qwen2,
+            "sizes": SIZES, "job": {"rows": 2, "seq": 128, "micro_batch": 1}}
 
 
 def _op(a, b, name="%fusion.1 = f32[8] fusion(f32[8] %p)"):
@@ -65,6 +62,9 @@ def test_synthetic_metrics():
     assert read("executor_host_ms") == pytest.approx(5.0)
     assert read("stage_device_ms") == pytest.approx(3.5)
     assert read("adam_device_ms") == pytest.approx(3.0)
+    # the architecture module's model FLOPs over the 10-ms window at 1e12
+    assert read("mfu") == pytest.approx(
+        100 * qwen2.model_flops_train(SIZES, 2, 128) / (10e-3 * 1e12))
     # one 0.5 ms kernel; 2 layers x 2 rows x 1 step calls
     f, b = flops.flash_attention_work(1, 128, 4, 4, 16)
     least = 4 * max(f / 1e12, b / 1e11)
