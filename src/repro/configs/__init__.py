@@ -24,6 +24,7 @@ from repro.configs.internvl2_1b import CONFIG as _internvl2_1b
 from repro.configs.granite_moe_1b_a400m import CONFIG as _granite_moe
 from repro.configs.gpt3_96b import CONFIG as _gpt3_96b
 from repro.configs.llama_65b import CONFIG as _llama_65b
+from repro.configs.moonlight_16b_a3b import CONFIG as _moonlight_16b_a3b
 
 # The ten architectures assigned to this paper (public pool).
 ASSIGNED = (
@@ -45,7 +46,7 @@ _REGISTRY: Dict[str, ModelConfig] = {
         _recurrentgemma_2b, _qwen3_14b, _gemma2_9b, _llama4_scout,
         _xlstm_125m, _qwen1_5_32b, _qwen1_5_0_5b, _qwen1_5_0_5b_swa,
         _whisper_small, _internvl2_1b, _granite_moe,
-        _gpt3_96b, _llama_65b,
+        _gpt3_96b, _llama_65b, _moonlight_16b_a3b,
     )
 }
 

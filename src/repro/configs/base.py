@@ -19,18 +19,52 @@ LOCAL = "local_attn"     # sliding-window causal attention
 RGLRU = "rglru"          # Griffin-style gated linear recurrent unit block
 MLSTM = "mlstm"          # xLSTM matrix-memory cell (chunkwise parallel)
 SLSTM = "slstm"          # xLSTM scalar-memory cell (sequential scan)
+MLA = "mla"              # DeepSeek-V3 multi-head latent attention (causal)
 
-MIXER_KINDS = (ATTN, LOCAL, RGLRU, MLSTM, SLSTM)
+MIXER_KINDS = (ATTN, LOCAL, RGLRU, MLSTM, SLSTM, MLA)
+ATTENTION_KINDS = (ATTN, LOCAL, MLA)
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    num_experts: int               # experts the router scores
     top_k: int
     d_ff: int                      # per-expert hidden size
-    shared_expert: bool = False    # llama4-style always-on shared expert
+    # always-on shared experts of d_ff each, run as one SwiGLU of
+    # shared_experts * d_ff (llama4: 1, DeepSeek-V3: n_shared_experts)
+    shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # softmax: gates are the top-k softmax probabilities, renormalised.
+    # sigmoid (DeepSeek-V3 noaux_tc): top-k of sigmoid scores plus a
+    # per-expert selection bias; the gates are the chosen scores,
+    # normalised to sum 1 and times routed_scale; no aux loss.
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # dropless: every pick is computed (sorted by expert, grouped
+    # matmuls); otherwise picks past the capacity bound are dropped.
+    dropless: bool = False
+    # Experts held by this layer (expert parallelism): experts
+    # [first_held, first_held + held) of num_experts; 0 = all of them.
+    # The layer routes over all experts and adds only its own part.
+    held: int = 0
+    first_held: int = 0
+
+    def __post_init__(self):
+        assert self.scoring in ("softmax", "sigmoid"), self.scoring
+        assert 0 <= self.first_held <= self.num_experts - self.n_held
+        assert self.dropless or self.n_held == self.num_experts, (
+            "holding a share of the experts needs dropless routing")
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+    @property
+    def shared_expert(self) -> bool:
+        """Whether any shared expert runs (the benchmark's frozen FLOP
+        copy, ``bench/flops.py``, reads this name)."""
+        return self.shared_experts > 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,6 +81,9 @@ class ModelConfig:
 
     head_dim: int = 0              # 0 -> d_model // num_heads
     block_pattern: Tuple[str, ...] = (ATTN,)
+    # Leading layers with a dense FFN of d_ff ahead of the pattern's
+    # layers, whose FFN is the MoE (DeepSeek-V3's first_k_dense_replace).
+    first_k_dense: int = 0
 
     # --- attention options -------------------------------------------------
     qk_norm: bool = False          # qwen3
@@ -56,6 +93,12 @@ class ModelConfig:
     window_size: int = 0           # sliding window for LOCAL layers
     rope_theta: float = 10_000.0
     attn_impl: str = "reference"   # reference | recompute | flash
+    # MLA (kind "mla"): queries and keys are head_dim (no rotary) +
+    # qk_rope_head_dim (rotary) wide, values v_head_dim; keys and values
+    # come from a kv_lora_rank latent and one rotary key shared by heads.
+    kv_lora_rank: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0            # 0 -> head_dim
 
     # --- FFN / MoE ----------------------------------------------------------
     mlp_kind: str = "swiglu"       # swiglu | gelu
@@ -74,6 +117,7 @@ class ModelConfig:
     # --- numerics -------------------------------------------------------------
     dtype: str = "bfloat16"
     norm: str = "rmsnorm"          # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     tie_embeddings: bool = False
 
     # --- lowering strategy -----------------------------------------------------
@@ -100,6 +144,9 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.rnn_width == 0:
             object.__setattr__(self, "rnn_width", self.d_model)
+        assert 0 <= self.first_k_dense < self.num_layers, self.name
+        if MLA in self.block_pattern:
+            assert self.kv_lora_rank and self.qk_rope_head_dim, self.name
         for k in self.block_pattern:
             assert k in MIXER_KINDS, k
         assert self.num_heads % self.num_kv_heads == 0, (
@@ -111,24 +158,61 @@ class ModelConfig:
         return self.encoder_layers > 0
 
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The mixer kind of every (decoder) layer, pattern repeated."""
+        """The mixer kind of every (decoder) layer: the first_k_dense
+        leading layers take the pattern's first kind, then the pattern
+        repeats."""
         pat = self.block_pattern
-        reps = (self.num_layers + len(pat) - 1) // len(pat)
-        return tuple((pat * reps)[: self.num_layers])
+        k = self.first_k_dense
+        n = self.num_layers - k
+        reps = (n + len(pat) - 1) // len(pat)
+        return (pat[0],) * k + tuple((pat * reps)[:n])
+
+    @property
+    def v_dim(self) -> int:
+        """Width of a value head (MLA's v_head_dim, else head_dim)."""
+        return self.v_head_dim or self.head_dim
+
+    def layer_is_moe(self, layer: int) -> bool:
+        """Whether decoder layer ``layer``'s FFN is the MoE."""
+        return self.moe is not None and layer >= self.first_k_dense
 
     @property
     def sub_quadratic(self) -> bool:
         """True if no layer does *global* attention over the full sequence
         (the assignment's criterion for running long_500k)."""
-        return ATTN not in self.layer_kinds()
+        kinds = self.layer_kinds()
+        return ATTN not in kinds and MLA not in kinds
+
+    def attn_params(self) -> int:
+        """Parameters of one attention mixer (MLA or q/k/v/o)."""
+        d, hd, n_q = self.d_model, self.head_dim, self.num_heads
+        if MLA in self.block_pattern:
+            r, rope, vd = (self.kv_lora_rank, self.qk_rope_head_dim,
+                           self.v_dim)
+            return (d * n_q * (hd + rope) + d * (r + rope) + r
+                    + r * n_q * (hd + vd) + n_q * vd * d)
+        n_kv = self.num_kv_heads
+        attn = d * hd * (n_q + 2 * n_kv) + n_q * hd * d
+        if self.qkv_bias:
+            attn += hd * (n_q + 2 * n_kv)
+        return attn
+
+    def moe_params(self) -> int:
+        """Parameters of one MoE FFN as held here: the router over every
+        expert, its selection bias, the held experts and the shared
+        ones."""
+        e, d = self.moe, self.d_model
+        total = d * e.num_experts + e.n_held * 3 * d * e.d_ff
+        total += e.shared_experts * 3 * d * e.d_ff
+        if e.scoring == "sigmoid":
+            total += e.num_experts
+        return total
 
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks)."""
         d, hd = self.d_model, self.head_dim
-        n_q, n_kv = self.num_heads, self.num_kv_heads
-        attn = d * hd * (n_q + 2 * n_kv) + n_q * hd * d
-        if self.qkv_bias:
-            attn += hd * (n_q + 2 * n_kv)
+        n_q = self.num_heads
+        attn = self.attn_params()
         ffn_dense = 0
         if self.d_ff:
             ffn_dense = (3 if self.mlp_kind == "swiglu" else 2) * d * self.d_ff
@@ -137,19 +221,15 @@ class ModelConfig:
             w = self.rnn_width
             rglru = 2 * d * w + w * d + self.conv_width * w + 2 * w * w + 2 * w
         total = 0
-        for kind in self.layer_kinds():
-            if kind in (ATTN, LOCAL):
+        for i, kind in enumerate(self.layer_kinds()):
+            if kind in ATTENTION_KINDS:
                 total += attn
             elif kind == RGLRU:
                 total += rglru
             elif kind in (MLSTM, SLSTM):
                 total += 4 * d * n_q * hd + n_q * hd * d + 3 * n_q * hd
-            if self.moe is not None:
-                e = self.moe
-                total += d * e.num_experts  # router
-                total += e.num_experts * 3 * d * e.d_ff
-                if e.shared_expert:
-                    total += 3 * d * e.d_ff
+            if self.layer_is_moe(i):
+                total += self.moe_params()
             elif self.d_ff:
                 total += ffn_dense
             total += 2 * d  # norms
@@ -182,7 +262,8 @@ class ModelConfig:
         if self.moe is not None:
             moe = dataclasses.replace(
                 self.moe, num_experts=min(self.moe.num_experts, 4),
-                top_k=min(self.moe.top_k, 2), d_ff=128)
+                top_k=min(self.moe.top_k, 2), d_ff=128, held=0,
+                first_held=0)
         kw = dict(
             name=self.name + "-smoke",
             num_layers=n_layers,
@@ -199,6 +280,10 @@ class ModelConfig:
             encoder_layers=2 if self.encoder_layers else 0,
             num_prefix_embeds=min(self.num_prefix_embeds, 4),
         )
+        if self.kv_lora_rank:
+            kw.update(kv_lora_rank=min(self.kv_lora_rank, 64),
+                      qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+                      v_head_dim=head_dim)
         kw.update(overrides)
         new = dataclasses.replace(self, **kw)
         object.__setattr__(new, "rnn_width", kw["rnn_width"] or d_model)

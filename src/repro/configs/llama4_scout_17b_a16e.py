@@ -18,6 +18,6 @@ CONFIG = ModelConfig(
     d_ff=0,  # all FFN capacity is in the MoE
     vocab_size=202_048,
     block_pattern=(ATTN,),
-    moe=MoEConfig(num_experts=16, top_k=1, d_ff=8192, shared_expert=True),
+    moe=MoEConfig(num_experts=16, top_k=1, d_ff=8192, shared_experts=1),
     mlp_kind="swiglu",
 )

@@ -7,7 +7,8 @@ FFN matmuls to 8/3 h cost 16 b s h^2, identical to GPT-3's 4h FFN.
 """
 from __future__ import annotations
 
-from repro.configs.base import ATTN, LOCAL, MLSTM, RGLRU, SLSTM, ModelConfig
+from repro.configs.base import (ATTN, LOCAL, MLA, MLSTM, RGLRU, SLSTM,
+                                ModelConfig)
 from repro.core.notation import Notation
 
 
@@ -31,12 +32,24 @@ def stage_flops(n: Notation) -> float:
 # ---------------------------------------------------------------------------
 # General per-architecture matmul FLOPs (for the assigned archs / roofline).
 # ---------------------------------------------------------------------------
-def layer_flops_fwd(cfg: ModelConfig, kind: str, b: int, s: int) -> float:
-    """Forward matmul FLOPs of one layer (global batch slice b, seq s)."""
+def layer_flops_fwd(cfg: ModelConfig, kind: str, b: int, s: int,
+                    moe: bool = None) -> float:
+    """Forward matmul FLOPs of one layer (global batch slice b, seq s);
+    ``moe`` says whether its FFN is the MoE (default: where the config
+    has one). A dropless MoE counts the picks of the experts held, at
+    their expected share ``n_held / num_experts`` of the picks."""
     d, hd = cfg.d_model, cfg.head_dim
     nq, nkv = cfg.num_heads, cfg.num_kv_heads
+    moe = cfg.moe is not None if moe is None else moe
     f = 0.0
-    if kind in (ATTN, LOCAL):
+    if kind == MLA:
+        rot, r, vd = cfg.qk_rope_head_dim, cfg.kv_lora_rank, cfg.v_dim
+        f += 2 * b * s * d * nq * (hd + rot)              # q proj
+        f += 2 * b * s * d * (r + rot)                    # latent + rope key
+        f += 2 * b * s * r * nq * (hd + vd)               # k_nope, v
+        f += 2 * b * s * nq * vd * d                      # out proj
+        f += 2 * b * nq * s * s * (hd + rot + vd) * 0.5   # qk^T and pv, causal
+    elif kind in (ATTN, LOCAL):
         f += 2 * b * s * d * hd * (nq + 2 * nkv)          # qkv proj
         f += 2 * b * s * nq * hd * d                      # out proj
         ctx = min(s, cfg.window_size) if (kind == LOCAL and cfg.window_size) else s
@@ -53,12 +66,13 @@ def layer_flops_fwd(cfg: ModelConfig, kind: str, b: int, s: int) -> float:
             f += 2 * b * s * nq * (L * hd + 2 * hd * hd)  # intra scores + state
         else:
             f += 2 * b * s * nq * hd * hd * 4             # recurrent R matmuls
-    if cfg.moe is not None:
+    if moe:
         e = cfg.moe
         f += 2 * b * s * d * e.num_experts                # router
-        f += 2 * b * s * e.top_k * e.capacity_factor * 3 * d * e.d_ff
-        if e.shared_expert:
-            f += 2 * b * s * 3 * d * e.d_ff
+        picks = (e.top_k * e.n_held / e.num_experts if e.dropless
+                 else e.top_k * e.capacity_factor)
+        f += 2 * b * s * picks * 3 * d * e.d_ff
+        f += 2 * b * s * e.shared_experts * 3 * d * e.d_ff
     elif cfg.d_ff:
         n_mat = 3 if cfg.mlp_kind == "swiglu" else 2
         f += 2 * b * s * n_mat * d * cfg.d_ff
@@ -67,7 +81,8 @@ def layer_flops_fwd(cfg: ModelConfig, kind: str, b: int, s: int) -> float:
 
 def model_flops_fwd(cfg: ModelConfig, b: int, s: int,
                     include_encoder: bool = True) -> float:
-    f = sum(layer_flops_fwd(cfg, k, b, s) for k in cfg.layer_kinds())
+    f = sum(layer_flops_fwd(cfg, k, b, s, cfg.layer_is_moe(i))
+            for i, k in enumerate(cfg.layer_kinds()))
     if cfg.encoder_layers:
         from repro.models.model import ENCODER_FRAMES
         if include_encoder:
@@ -100,8 +115,10 @@ def model_flops_6nd(cfg: ModelConfig, b: int, s: int) -> float:
     n_active = cfg.param_count()
     if cfg.moe is not None:
         e = cfg.moe
-        routed_all = cfg.num_layers * e.num_experts * 3 * cfg.d_model * e.d_ff
-        routed_active = cfg.num_layers * e.top_k * 3 * cfg.d_model * e.d_ff
+        n_moe = sum(map(cfg.layer_is_moe, range(cfg.num_layers)))
+        routed_all = n_moe * e.n_held * 3 * cfg.d_model * e.d_ff
+        routed_active = (n_moe * e.top_k * e.n_held / e.num_experts
+                         * 3 * cfg.d_model * e.d_ff)
         n_active = n_active - routed_all + routed_active
     # embeddings don't do matmul work per token; subtract the table
     n_active -= cfg.vocab_size * cfg.d_model * (1 if cfg.tie_embeddings else 2)

@@ -21,7 +21,9 @@ per m queries (the same reuse flash-attention-2 gets from its head
 grouping).
 
 Supports: causal masking, sliding-window (local) masking, gemma2-style
-logit softcap, and a ``q_offset`` for sequence-sliced attention.
+logit softcap, a ``q_offset`` for sequence-sliced attention, and a value
+head narrower or wider than the query/key head (MLA: q/k 192, v 128);
+the output and dv take v's width, dq and dk q/k's.
 """
 from __future__ import annotations
 
@@ -139,6 +141,7 @@ def _prepare(q, k, v, block_q, block_k):
     kh = _pad_seq(k.transpose(0, 2, 1, 3), pad_k, axis=2)
     vh = _pad_seq(v.transpose(0, 2, 1, 3), pad_k, axis=2)
     return qh, kh, vh, dict(b=b, sq=sq, sk=sk, nkv=nkv, m=m, hd=hd,
+                            hd_v=v.shape[3],
                             block_q=block_q, block_k=block_k,
                             nq_blocks=(sq + pad_q) // block_q,
                             nkv_blocks=(sk + pad_k) // block_k)
@@ -174,8 +177,10 @@ def _row_stat(x, nkv, pad_q):
 def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
                         scale=None, block_q=128, block_k=128,
                         interpret=False, return_lse=False, q_offset=0):
-    """q: (b, sq, nq, hd); k/v: (b, sk, nkv, hd). Returns (b, sq, nq, hd)
-    and, with ``return_lse``, the (b, sq, nkv, m) log-sum-exp rows.
+    """q: (b, sq, nq, hd); k: (b, sk, nkv, hd); v: (b, sk, nkv, hd_v).
+    Returns (b, sq, nq, hd_v) and, with ``return_lse``, the
+    (b, sq, nkv, m) log-sum-exp rows. The scale defaults to
+    1/sqrt(hd).
 
     ``q_offset`` shifts the queries' positions for the causal/window
     masks: query row i is at global position i + q_offset while keys
@@ -183,7 +188,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
     retained prefix of q_offset earlier keys (docs/longcontext.md).
     """
     qh, kh, vh, t = _prepare(q, k, v, block_q, block_k)
-    b, nkv, m, hd = t["b"], t["nkv"], t["m"], t["hd"]
+    b, nkv, m, hd, hd_v = (t[x] for x in ("b", "nkv", "m", "hd", "hd_v"))
     bq, bk = t["block_q"], t["block_k"]
     sq_p = qh.shape[3]
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
@@ -191,25 +196,24 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, softcap=0.0,
         _kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=bq, block_k=bk,
         nkv_blocks=t["nkv_blocks"], kv_len=t["sk"], q_offset=q_offset)
-    q_spec = pl.BlockSpec((1, 1, m, bq, hd),
-                          lambda bb, g, qi, ki: (bb, g, 0, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, hd),
-                           lambda bb, g, qi, ki: (bb, g, ki, 0))
+    q_map = lambda bb, g, qi, ki: (bb, g, 0, qi, 0)  # noqa: E731
+    kv_map = lambda bb, g, qi, ki: (bb, g, ki, 0)  # noqa: E731
     out, lse = pl.pallas_call(
         kernel,
         grid=(b, nkv, t["nq_blocks"], t["nkv_blocks"]),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec,
-                   pl.BlockSpec((1, 1, m, bq, LANES),
-                                lambda bb, g, qi, ki: (bb, g, 0, qi, 0))],
+        in_specs=[pl.BlockSpec((1, 1, m, bq, hd), q_map),
+                  pl.BlockSpec((1, 1, bk, hd), kv_map),
+                  pl.BlockSpec((1, 1, bk, hd_v), kv_map)],
+        out_specs=[pl.BlockSpec((1, 1, m, bq, hd_v), q_map),
+                   pl.BlockSpec((1, 1, m, bq, LANES), q_map)],
         out_shape=[
-            jax.ShapeDtypeStruct(qh.shape, q.dtype),
+            jax.ShapeDtypeStruct(qh.shape[:4] + (hd_v,), q.dtype),
             jax.ShapeDtypeStruct((b, nkv, m, sq_p, LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((m * bq, LANES), jnp.float32),
             pltpu.VMEM((m * bq, LANES), jnp.float32),
-            pltpu.VMEM((m * bq, hd), jnp.float32),
+            pltpu.VMEM((m * bq, hd_v), jnp.float32),
         ],
         compiler_params=_PARALLEL,
         interpret=interpret,
@@ -293,12 +297,13 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
                         interpret=False, q_offset=0):
     """dq, dk, dv via the two-pass flash backward.
 
-    q/dout: (b, sq, nq, hd); k/v: (b, sk, nkv, hd);
-    lse: (b, sq, nkv, m) from the forward. ``q_offset`` as in the fwd.
+    q: (b, sq, nq, hd); k: (b, sk, nkv, hd); v: (b, sk, nkv, hd_v);
+    out/dout: (b, sq, nq, hd_v); lse: (b, sq, nkv, m) from the forward.
+    ``q_offset`` as in the fwd.
     """
     qh, kh, vh, t = _prepare(q, k, v, block_q, block_k)
-    b, sq, sk, nkv, m, hd = (t[x] for x in ("b", "sq", "sk", "nkv", "m",
-                                            "hd"))
+    b, sq, sk, nkv, m, hd, hd_v = (t[x] for x in ("b", "sq", "sk", "nkv",
+                                                  "m", "hd", "hd_v"))
     bq, bk = t["block_q"], t["block_k"]
     pad_q = qh.shape[3] - sq
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
@@ -315,12 +320,14 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
     def specs(q_map, kv_map):
         qs = pl.BlockSpec((1, 1, m, bq, hd), q_map)
         kvs = pl.BlockSpec((1, 1, bk, hd), kv_map)
+        vs = pl.BlockSpec((1, 1, bk, hd_v), kv_map)
+        dos = pl.BlockSpec((1, 1, m, bq, hd_v), q_map)
         stat = pl.BlockSpec((1, 1, m, bq, LANES), q_map)
-        return [qs, kvs, kvs, qs, stat, stat], qs, kvs
+        return [qs, kvs, vs, dos, stat, stat], qs, kvs, vs
 
     # --- pass 1: dq; grid (b, nkv, q_blocks, kv_blocks[arbitrary]) ----------
-    in_specs, q_spec, _ = specs(lambda bb, g, qi, ki: (bb, g, 0, qi, 0),
-                                lambda bb, g, qi, ki: (bb, g, ki, 0))
+    in_specs, q_spec, _, _ = specs(lambda bb, g, qi, ki: (bb, g, 0, qi, 0),
+                                   lambda bb, g, qi, ki: (bb, g, ki, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, nkv_blocks=t["nkv_blocks"], **common),
         grid=(b, nkv, t["nq_blocks"], t["nkv_blocks"]),
@@ -334,17 +341,18 @@ def flash_attention_bwd(q, k, v, out, lse, dout, *, causal=True, window=0,
     )(*operands)
 
     # --- pass 2: dk/dv; grid (b, nkv, kv_blocks, q_blocks[arbitrary]) -------
-    in_specs, _, kv_spec = specs(lambda bb, g, ki, qi: (bb, g, 0, qi, 0),
-                                 lambda bb, g, ki, qi: (bb, g, ki, 0))
+    in_specs, _, kv_spec, v_spec = specs(
+        lambda bb, g, ki, qi: (bb, g, 0, qi, 0),
+        lambda bb, g, ki, qi: (bb, g, ki, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, nq_blocks=t["nq_blocks"], **common),
         grid=(b, nkv, t["nkv_blocks"], t["nq_blocks"]),
         in_specs=in_specs,
-        out_specs=[kv_spec, kv_spec],
+        out_specs=[kv_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(kh.shape, k.dtype),
                    jax.ShapeDtypeStruct(vh.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
-                        pltpu.VMEM((bk, hd), jnp.float32)],
+                        pltpu.VMEM((bk, hd_v), jnp.float32)],
         compiler_params=_PARALLEL,
         interpret=interpret,
         name="flash_bwd_dkv",

@@ -4,6 +4,9 @@
 dk/dv kernels recomputing probabilities from the saved LSE — the
 flash-attention-2 scheme).
 ``fused_softmax``: Pallas forward and backward kernels via custom_vjp.
+``grouped_matmul``: the rows of each group times that group's matrix, as
+JAX's megablox Pallas kernels (``gmm`` forward and input gradient,
+``tgmm`` weight gradient), over the groups a layer holds.
 """
 from __future__ import annotations
 
@@ -11,6 +14,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from jax.experimental.pallas.ops.tpu.megablox import ops as _megablox
 
 from repro.kernels import fused_softmax as _fs
 from repro.kernels import ref as _ref
@@ -111,3 +116,23 @@ def unfused_softmax_chain(x, scale=1.0, causal=False):
         xf = jnp.where(mask, xf, _ref.NEG_INF)
     y = jax.nn.softmax(xf, axis=-1)
     return y.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+def _gmm_tiling(m, k, n):
+    """Tile sizes (rows, contraction, output columns) of a grouped matmul
+    whose lhs has m rows: row tiles divide m; a partial last contraction
+    or column tile is masked by the kernel."""
+    tm = next((t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0), m)
+    return tm, min(k, 512), min(n, 1024)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, group_offset=0, interpret=False):
+    """lhs (m, k) rows sorted by group, ``group_sizes`` (G,) int32 rows per
+    group; rhs (H, k, n) the matrices of groups [group_offset,
+    group_offset + H). Returns (m, n) in lhs's dtype: each held group's
+    rows times its matrix. Rows of groups not held are not computed and
+    hold no defined value; the caller masks them."""
+    return _megablox.gmm(lhs, rhs, group_sizes, lhs.dtype, _gmm_tiling,
+                         jnp.asarray(group_offset, jnp.int32), None, False,
+                         interpret)

@@ -47,7 +47,7 @@ def _project_q(p, x, cfg, positions):
     if "bq" in p:
         q = q + p["bq"].astype(dt)
     if "qnorm" in p:
-        q = apply_norm(p["qnorm"], q)
+        q = apply_norm(p["qnorm"], q, cfg.norm_eps)
     if positions is not None:
         q = rope(q, positions, cfg.rope_theta)
     return q
@@ -61,7 +61,7 @@ def _project_kv(p, x, cfg, positions):
         k = k + p["bk"].astype(dt)
         v = v + p["bv"].astype(dt)
     if "knorm" in p:
-        k = apply_norm(p["knorm"], k)
+        k = apply_norm(p["knorm"], k, cfg.norm_eps)
     if positions is not None:
         k = rope(k, positions, cfg.rope_theta)
     return k, v
@@ -70,7 +70,7 @@ def _project_kv(p, x, cfg, positions):
 def _sdpa(q, k, v, cfg, q_pos, k_pos, *, causal, window):
     """Reference scaled-dot-product attention with additive masking.
 
-    q: (b, sq, nq, hd); k/v: (b, sk, nkv, hd); *_pos: (b, s*) int32.
+    q/k: (b, s*, n*, hd); v: (b, sk, nkv, hd_v); *_pos: (b, s*) int32.
     Computed in fp32 (the paper's exp-(7) pathology: on GPU this upcast
     chain ran as separate unfused kernels; XLA fuses it — see DESIGN.md).
     """
@@ -94,7 +94,7 @@ def _sdpa(q, k, v, cfg, q_pos, k_pos, *, causal, window):
     scores = jnp.where(mask, scores, neg)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgmqk,bkgh->bqgmh", probs, v)
-    return out.reshape(b, sq, nq, hd)
+    return out.reshape(b, sq, nq, v.shape[-1])
 
 
 def _flash(q, k, v, cfg, *, causal, window, q_offset=0):

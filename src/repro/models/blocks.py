@@ -8,7 +8,9 @@ with ``lax.scan`` — HLO stays O(pattern), not O(depth), which keeps the
 unrolled at the end.
 
 Each layer = mixer + optional cross-attention (enc-dec) + FFN (dense or
-MoE), pre-norm residual.
+MoE), pre-norm residual. ``cfg.first_k_dense`` leading layers (``lead<i>``,
+unrolled ahead of the scan) take a dense FFN of ``d_ff`` where the
+pattern's layers take the MoE (DeepSeek-V3's first_k_dense_replace).
 """
 from __future__ import annotations
 
@@ -18,8 +20,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, LOCAL, MLSTM, RGLRU, SLSTM
+from repro.configs.base import ATTN, LOCAL, MLA, MLSTM, RGLRU, SLSTM
 from repro.models import attention as attn_mod
+from repro.models import mla as mla_mod
 from repro.models import moe as moe_mod
 from repro.models import recurrent as rec_mod
 from repro.models import xlstm as xlstm_mod
@@ -29,11 +32,15 @@ from repro.models.layers import apply_mlp, apply_norm, init_mlp, init_norm
 # ---------------------------------------------------------------------------
 # Single-layer init / apply
 # ---------------------------------------------------------------------------
-def init_layer(key, cfg, kind, *, cross=False):
+def init_layer(key, cfg, kind, *, cross=False, dense=False):
+    """One layer's params; ``dense`` gives a MoE config's layer the dense
+    FFN of ``d_ff`` (a leading dense layer)."""
     ks = jax.random.split(key, 4)
     p: Dict[str, Any] = {"norm1": init_norm(cfg)}
     if kind in (ATTN, LOCAL):
         p["mixer"] = attn_mod.init_attention(ks[0], cfg)
+    elif kind == MLA:
+        p["mixer"] = mla_mod.init_mla(ks[0], cfg)
     elif kind == RGLRU:
         p["mixer"] = rec_mod.init_rglru_block(ks[0], cfg)
     elif kind == MLSTM:
@@ -43,7 +50,7 @@ def init_layer(key, cfg, kind, *, cross=False):
     if cross:
         p["norm_x"] = init_norm(cfg)
         p["cross"] = attn_mod.init_attention(ks[1], cfg, cross=True)
-    if cfg.moe is not None:
+    if cfg.moe is not None and not dense:
         p["norm2"] = init_norm(cfg)
         p["ffn"] = moe_mod.init_moe(ks[2], cfg)
     elif cfg.d_ff:
@@ -58,6 +65,8 @@ def _apply_mixer(p, x, cfg, kind, positions, *, causal, remat):
             out, _ = attn_mod.attention(p_, x_, cfg, positions, kind=kind,
                                         causal=causal)
             return out
+        if kind == MLA:
+            return mla_mod.mla(p_, x_, cfg, positions)
         if kind == RGLRU:
             return rec_mod.apply_rglru_block(p_, x_, cfg)
         if kind == MLSTM:
@@ -66,7 +75,7 @@ def _apply_mixer(p, x, cfg, kind, positions, *, causal, remat):
             return xlstm_mod.apply_slstm_block(p_, x_, cfg)
         raise ValueError(kind)
 
-    if remat == "attn" and kind in (ATTN, LOCAL):
+    if remat == "attn" and kind in (ATTN, LOCAL, MLA):
         f = jax.checkpoint(f)
     return f(p, x)
 
@@ -100,32 +109,49 @@ def apply_layer_sliced(p, x, cfg, kind, positions, kv_prefix, *,
     if remat == "attn":
         mix = jax.checkpoint(mix)
     aux = 0.0
-    h, kv = mix(p["mixer"], apply_norm(p["norm1"], x), kv_prefix)
+    h, kv = mix(p["mixer"], apply_norm(p["norm1"], x, cfg.norm_eps),
+                kv_prefix)
     x = x + h
     if "ffn" in p:
-        h, aux = _apply_ffn(p["ffn"], apply_norm(p["norm2"], x), cfg)
+        h, aux = _ffn_sublayer(p, x, cfg)
         x = x + h
     return x, aux, kv
 
 
 def _apply_ffn(p, x, cfg):
-    if cfg.moe is not None:
+    if "router" in p:
         return moe_mod.apply_moe(p, x, cfg)
     return apply_mlp(p, x, cfg), 0.0
+
+
+def _ffn_sublayer(p, x, cfg):
+    """The FFN's residual branch of one layer: (branch output, aux).
+
+    With a dropless MoE every layer's FFN (norm, router, experts, MLP) is
+    recomputed in the backward instead of stashing its activations and
+    bf16 weights per micro-batch: the dispatch buffers are sized for the
+    worst case, top_k times the tokens."""
+    def f(p_, x_):
+        return _apply_ffn(p_["ffn"], apply_norm(p_["norm2"], x_,
+                                                cfg.norm_eps), cfg)
+
+    if cfg.moe is not None and cfg.moe.dropless:
+        f = jax.checkpoint(f)
+    return f({"ffn": p["ffn"], "norm2": p["norm2"]}, x)
 
 
 def apply_layer(p, x, cfg, kind, positions, *, enc_states=None,
                 causal=True, remat="none"):
     """Train/prefill layer. Returns (x, aux_loss)."""
     aux = 0.0
-    h = _apply_mixer(p["mixer"], apply_norm(p["norm1"], x), cfg, kind,
-                     positions, causal=causal, remat=remat)
+    h = _apply_mixer(p["mixer"], apply_norm(p["norm1"], x, cfg.norm_eps),
+                     cfg, kind, positions, causal=causal, remat=remat)
     x = x + h
     if "cross" in p:
-        xc = apply_norm(p["norm_x"], x)
+        xc = apply_norm(p["norm_x"], x, cfg.norm_eps)
         x = x + attn_mod.cross_attention(p["cross"], xc, enc_states, cfg)
     if "ffn" in p:
-        h, aux = _apply_ffn(p["ffn"], apply_norm(p["norm2"], x), cfg)
+        h, aux = _ffn_sublayer(p, x, cfg)
         x = x + h
     return x, aux
 
@@ -145,7 +171,7 @@ def init_layer_state(cfg, kind, batch, max_len, dtype):
 
 def apply_layer_prefill(p, x, cfg, kind, positions, state, *, enc_states=None):
     """Like apply_layer but also fills this layer's decode state."""
-    xn = apply_norm(p["norm1"], x)
+    xn = apply_norm(p["norm1"], x, cfg.norm_eps)
     if kind in (ATTN, LOCAL):
         h, (k, v) = attn_mod.attention(p["mixer"], xn, cfg, positions, kind=kind)
         new_state = attn_mod.fill_kv_cache(state, k, v)
@@ -164,10 +190,11 @@ def apply_layer_prefill(p, x, cfg, kind, positions, state, *, enc_states=None):
         raise ValueError(kind)
     x = x + h
     if "cross" in p:
-        xc = apply_norm(p["norm_x"], x)
+        xc = apply_norm(p["norm_x"], x, cfg.norm_eps)
         x = x + attn_mod.cross_attention(p["cross"], xc, enc_states, cfg)
     if "ffn" in p:
-        h, _ = _apply_ffn(p["ffn"], apply_norm(p["norm2"], x), cfg)
+        h, _ = _apply_ffn(p["ffn"], apply_norm(p["norm2"], x, cfg.norm_eps),
+                          cfg)
         x = x + h
     return x, new_state
 
@@ -190,7 +217,7 @@ def _rglru_prefill(p, xn, cfg, state):
 
 def apply_layer_decode(p, x, cfg, kind, pos, state, *, enc_states=None):
     """One-token decode. x: (b, 1, d). Returns (x, new_state)."""
-    xn = apply_norm(p["norm1"], x)
+    xn = apply_norm(p["norm1"], x, cfg.norm_eps)
     if kind in (ATTN, LOCAL):
         h, state = attn_mod.attention_decode(p["mixer"], xn, cfg, state, pos, kind=kind)
     elif kind == RGLRU:
@@ -203,10 +230,11 @@ def apply_layer_decode(p, x, cfg, kind, pos, state, *, enc_states=None):
         raise ValueError(kind)
     x = x + h
     if "cross" in p:
-        xc = apply_norm(p["norm_x"], x)
+        xc = apply_norm(p["norm_x"], x, cfg.norm_eps)
         x = x + attn_mod.cross_attention(p["cross"], xc, enc_states, cfg)
     if "ffn" in p:
-        h, _ = _apply_ffn(p["ffn"], apply_norm(p["norm2"], x), cfg)
+        h, _ = _apply_ffn(p["ffn"], apply_norm(p["norm2"], x, cfg.norm_eps),
+                          cfg)
         x = x + h
     return x, state
 
@@ -215,21 +243,27 @@ def apply_layer_decode(p, x, cfg, kind, pos, state, *, enc_states=None):
 # PatternStack
 # ---------------------------------------------------------------------------
 class PatternStack:
-    """Static helper describing how num_layers decompose into scanned
-    pattern blocks + unrolled remainder layers."""
+    """Static helper describing how num_layers decompose into unrolled
+    leading dense layers (the decoder's ``cfg.first_k_dense``), scanned
+    pattern blocks and unrolled remainder layers."""
 
     def __init__(self, cfg, *, cross=False, num_layers=None, pattern=None):
         self.cfg = cfg
         self.cross = cross
         self.pattern = tuple(pattern or cfg.block_pattern)
+        self.lead = cfg.first_k_dense if num_layers is None else 0
         n = num_layers if num_layers is not None else cfg.num_layers
         self.num_layers = n
-        self.n_full = n // len(self.pattern)
-        self.rem = self.pattern[: n % len(self.pattern)]
+        self.n_full = (n - self.lead) // len(self.pattern)
+        self.rem = self.pattern[: (n - self.lead) % len(self.pattern)]
 
     # -- init ---------------------------------------------------------------
     def init(self, key):
         p = {}
+        for i in range(self.lead):
+            p[f"lead{i}"] = init_layer(
+                jax.random.fold_in(key, 2000 + i), self.cfg, self.pattern[0],
+                cross=self.cross, dense=True)
         for j, kind in enumerate(self.pattern):
             keys = jax.random.split(jax.random.fold_in(key, j), self.n_full)
             p[f"pos{j}"] = jax.vmap(
@@ -240,6 +274,8 @@ class PatternStack:
         return p
 
     def init_state(self, batch, max_len, dtype):
+        if self.lead:
+            raise NotImplementedError("decode through leading dense layers")
         st = {}
         n = self.n_full
         for j, kind in enumerate(self.pattern):
@@ -264,20 +300,27 @@ class PatternStack:
                 aux = aux + a
             return (x, aux), None
 
+        def lead(p_, x_):
+            return apply_layer(p_, x_, cfg, pattern[0], positions,
+                               enc_states=enc_states, causal=causal,
+                               remat=remat)
+
         if remat == "full":
-            blockf = jax.checkpoint(block)
+            blockf, lead = jax.checkpoint(block), jax.checkpoint(lead)
         else:
             blockf = block
+        aux = 0.0
+        for i in range(self.lead):
+            x, a = lead(params[f"lead{i}"], x)
+            aux = aux + a
         scanned = {k: v for k, v in params.items() if k.startswith("pos")}
         if self.n_full and cfg.scan_blocks:
-            (x, aux), _ = jax.lax.scan(blockf, (x, 0.0), scanned)
+            (x, aux), _ = jax.lax.scan(blockf, (x, aux), scanned)
         elif self.n_full:
-            carry = (x, 0.0)
+            carry = (x, aux)
             for i in range(self.n_full):
                 carry, _ = blockf(carry, jax.tree.map(lambda a: a[i], scanned))
             x, aux = carry
-        else:
-            aux = 0.0
         for i, kind in enumerate(self.rem):
             x, a = apply_layer(params[f"rem{i}"], x, cfg, kind, positions,
                                enc_states=enc_states, causal=causal, remat=remat)

@@ -101,7 +101,10 @@ def init_embed(key, cfg):
 
 
 def embed(params, tokens, cfg):
-    x = params["table"].astype(cdtype(cfg))[tokens]
+    # Gather the fp32 rows, then cast: the table's gradient is then a
+    # scatter-add in fp32. Cast first, it adds in bf16, and a token that
+    # fills much of a batch loses its gradient to rounding.
+    x = params["table"][tokens].astype(cdtype(cfg))
     if cfg.tie_embeddings:  # gemma-style scaled embeddings
         x = x * jnp.asarray(np.sqrt(cfg.d_model), x.dtype)
     return x

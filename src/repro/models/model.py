@@ -57,7 +57,7 @@ def encode(params, enc_embeds, cfg):
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x, _ = enc.apply(params["encoder"]["blocks"], x, positions, causal=False)
-    return apply_norm(params["encoder"]["norm"], x)
+    return apply_norm(params["encoder"]["norm"], x, cfg.norm_eps)
 
 
 def _embed_inputs(params, batch, cfg):
@@ -84,7 +84,7 @@ def forward(params, batch, cfg: ModelConfig, *, remat="none"):
     x, positions, n_prefix = _embed_inputs(params, batch, cfg)
     x, aux = dec.apply(params["blocks"], x, positions,
                        enc_states=enc_states, remat=remat)
-    x = apply_norm(params["final_norm"], x)
+    x = apply_norm(params["final_norm"], x, cfg.norm_eps)
     if n_prefix:
         x = x[:, n_prefix:]
     logits = unembed(params["embed"], x, cfg)
@@ -141,7 +141,7 @@ def prefill(params, batch, cfg: ModelConfig, state):
     x, positions, n_prefix = _embed_inputs(params, batch, cfg)
     x, state = dec.prefill(params["blocks"], x, positions, state,
                            enc_states=enc_states)
-    x = apply_norm(params["final_norm"], x[:, -1:])
+    x = apply_norm(params["final_norm"], x[:, -1:], cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg)[:, 0]
     return logits, state, enc_states
 
@@ -152,6 +152,6 @@ def decode_step(params, token, pos, state, cfg: ModelConfig, enc_states=None):
     dec, _ = _stacks(cfg)
     x, state = dec.decode(params["blocks"], x, pos, state,
                           enc_states=enc_states)
-    x = apply_norm(params["final_norm"], x)
+    x = apply_norm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg)[:, 0]
     return logits, state

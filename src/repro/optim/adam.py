@@ -3,16 +3,28 @@
 Mixed precision per the paper's setup: master params and both moments in
 fp32 (the models cast weights to bf16 at use — "cast-on-read"), gradients
 arrive fp32 from the fp32 loss.
+
+Weight decay applies by leaf, to the weight matrices named in ``decayed``
+(the last key of a leaf's path; ``DECAYED`` by default): never to norm
+scales, biases or a router's selection bias, stacked or not.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, Collection
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import TrainConfig
+
+
+#: The program's weight matrices (``models/``): embedding table and head;
+#: attention, MLA and xLSTM projections; FFN, expert and router matrices;
+#: RG-LRU projections, gates and conv taps; xLSTM recurrent matrices.
+DECAYED = frozenset({"table", "unembed", "wq", "wk", "wv", "wo", "wkv_a",
+                     "wkv_b", "wif", "wog", "w", "r", "wi", "wg", "router",
+                     "in_x", "in_g", "out", "wa", "wx", "conv_w"})
 
 
 @jax.tree_util.register_dataclass
@@ -43,8 +55,10 @@ def global_norm(tree):
 
 
 def update(params, grads, state: AdamState, tcfg: TrainConfig,
-           b1=0.9, b2=0.95, eps=1e-8):
-    """Returns (new_params, new_state, metrics)."""
+           b1=0.9, b2=0.95, eps=1e-8,
+           decayed: Collection[str] = DECAYED):
+    """Returns (new_params, new_state, metrics). Weight decay applies to
+    the leaves whose last key is in ``decayed``."""
     step = state.step + 1
     gn = global_norm(grads)
     clip = jnp.minimum(1.0, tcfg.grad_clip / (gn + 1e-9)) if tcfg.grad_clip else 1.0
@@ -55,12 +69,16 @@ def update(params, grads, state: AdamState, tcfg: TrainConfig,
     bc2 = 1 - b2 ** step.astype(jnp.float32)
     lr = lr_schedule(tcfg, state.step)
 
-    def upd(p, mu, nu):
+    def upd(path, p, mu, nu):
         d = (mu / bc1) / (jnp.sqrt(nu / bc2) + eps)
-        if tcfg.weight_decay and p.ndim >= 2:  # no decay on norms/biases
+        if tcfg.weight_decay and _key(path[-1]) in decayed:
             d = d + tcfg.weight_decay * p.astype(jnp.float32)
         return (p.astype(jnp.float32) - lr * d).astype(p.dtype)
 
-    new_params = jax.tree.map(upd, params, m, v)
+    new_params = jax.tree_util.tree_map_with_path(upd, params, m, v)
     return new_params, AdamState(step=step, m=m, v=v), {
         "grad_norm": gn, "lr": lr}
+
+
+def _key(k) -> str:
+    return str(getattr(k, "key", getattr(k, "name", getattr(k, "idx", k))))

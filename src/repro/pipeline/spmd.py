@@ -164,7 +164,7 @@ def pipeline_loss_fn(cfg: ModelConfig, p: int, num_micro: int, *,
             # inside a device-varying cond acquire pvary->psum transposes
             # that only the true-branch devices execute. The extra FLOPs
             # are netted out analytically in the roofline pass.)
-            xl = apply_norm(params["final_norm"], y)
+            xl = apply_norm(params["final_norm"], y, cfg.norm_eps)
             logits = unembed(params["embed"], xl, cfg)
             mask = (lbl_t >= 0).astype(jnp.float32)
             lbl = jnp.maximum(lbl_t, 0)

@@ -51,11 +51,13 @@ class StageSplitter:
         self.stack = PatternStack(cfg)
 
     def _layer_params(self, params, ℓ: int):
-        k = len(self.stack.pattern)
-        blk, j = divmod(ℓ, k)
+        k, lead = len(self.stack.pattern), self.stack.lead
+        if ℓ < lead:
+            return params["blocks"][f"lead{ℓ}"]
+        blk, j = divmod(ℓ - lead, k)
         if blk < self.stack.n_full:
             return jax.tree.map(lambda a: a[blk], params["blocks"][f"pos{j}"])
-        return params["blocks"][f"rem{ℓ - self.stack.n_full * k}"]
+        return params["blocks"][f"rem{ℓ - lead - self.stack.n_full * k}"]
 
     def split(self, params) -> List[Dict[str, Any]]:
         stages = []
@@ -73,17 +75,19 @@ class StageSplitter:
 
     def merge(self, stage_grads: List[Dict[str, Any]]):
         """Restack per-stage layer grads into full-model param structure."""
-        k = len(self.stack.pattern)
+        k, lead = len(self.stack.pattern), self.stack.lead
         per_layer = {}
         for sg, layers in zip(stage_grads, self.assign):
             for local, ℓ in enumerate(layers):
                 per_layer[ℓ] = sg["layers"][local]
-        blocks: Dict[str, Any] = {}
+        blocks: Dict[str, Any] = {f"lead{i}": per_layer[i]
+                                  for i in range(lead)}
         for j in range(k):
-            rows = [per_layer[blk * k + j] for blk in range(self.stack.n_full)]
+            rows = [per_layer[lead + blk * k + j]
+                    for blk in range(self.stack.n_full)]
             blocks[f"pos{j}"] = jax.tree.map(lambda *a: jnp.stack(a), *rows)
         for i in range(len(self.stack.rem)):
-            blocks[f"rem{i}"] = per_layer[self.stack.n_full * k + i]
+            blocks[f"rem{i}"] = per_layer[lead + self.stack.n_full * k + i]
         embed_grad = stage_grads[0]["embed"]
         tail = stage_grads[-1]
         embed_grad = jax.tree.map(jnp.add, embed_grad, tail["unembed"])
@@ -130,7 +134,7 @@ def make_stage_fn(cfg: ModelConfig, p: int, stage: int, remat: str = "none"):
             aux = aux + a
         if not last:
             return x, aux
-        x = apply_norm(sp["final_norm"], x)
+        x = apply_norm(sp["final_norm"], x, cfg.norm_eps)
         logits = unembed(sp["unembed"], x, cfg)
         labels = batch["labels"]
         mask = (labels >= 0).astype(jnp.float32)
@@ -186,7 +190,7 @@ def make_sliced_stage_fn(cfg: ModelConfig, p: int, stage: int,
         kv_own = tuple(kv_own)
         if not last:
             return (x, aux), kv_own
-        x = apply_norm(sp["final_norm"], x)
+        x = apply_norm(sp["final_norm"], x, cfg.norm_eps)
         logits = unembed(sp["unembed"], x, cfg)
         labels = batch["labels"]
         mask = (labels >= 0).astype(jnp.float32)

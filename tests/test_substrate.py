@@ -10,10 +10,11 @@ import pytest
 from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import ASSIGNED, get_config
+from repro.configs import ASSIGNED, get_config, list_configs
 from repro.configs.base import TrainConfig
 from repro.checkpoint import ckpt
 from repro.data.pipeline import DataConfig, make_batch
+from repro.models import model as M
 from repro.optim import adam
 from repro.sharding import rules
 
@@ -70,6 +71,47 @@ def test_lr_schedule_warmup_and_decay():
     lrs = [float(adam.lr_schedule(tcfg, s)) for s in range(100)]
     assert lrs[0] < lrs[9] <= 1.0
     assert lrs[-1] < lrs[20]
+
+
+#: Leaves that are not weight matrices: norm scales and biases (any leaf
+#: under a ``*norm*`` key), biases, the router's selection bias and the
+#: RG-LRU's per-channel decay. A leaf name missing from both this set and
+#: ``adam.DECAYED`` fails the test below.
+UNDECAYED = {"bias", "b", "ba", "bx", "bif", "bq", "bk", "bv", "conv_b",
+             "route_bias", "lam"}
+
+
+def _leaf_names(cfg):
+    shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]:
+        keys = [str(getattr(k, "key", getattr(k, "idx", k))) for k in path]
+        yield keys, leaf
+
+
+@pytest.mark.parametrize("name", list_configs())
+def test_weight_decay_applies_to_weight_matrices_only(name):
+    """Every registered config: each leaf is a weight matrix whose name
+    Adam decays, or a norm scale or bias, which it does not."""
+    for keys, leaf in _leaf_names(get_config(name)):
+        plain = any("norm" in k for k in keys[:-1]) or keys[-1] in UNDECAYED
+        if plain:
+            assert keys[-1] not in adam.DECAYED, keys
+        else:
+            assert keys[-1] in adam.DECAYED and leaf.ndim >= 2, keys
+
+
+def test_weight_decay_skips_stacked_norms_and_selection_bias():
+    tcfg = TrainConfig(learning_rate=1.0, warmup_steps=0, steps=10,
+                       weight_decay=0.1, grad_clip=0.0)
+    params = {"norm1": {"scale": jnp.ones((2, 4))},
+              "mixer": {"wq": jnp.ones((2, 4, 4))},
+              "ffn": {"route_bias": jnp.ones((2, 8))}}
+    zero = jax.tree.map(jnp.zeros_like, params)
+    new, _, _ = adam.update(params, zero, adam.init(params), tcfg)
+    assert float(jnp.max(new["mixer"]["wq"])) < 1.0
+    assert float(jnp.min(new["norm1"]["scale"])) == 1.0
+    assert float(jnp.min(new["ffn"]["route_bias"])) == 1.0
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,48 @@ def test_flash_attention_compiles_for_v5e(one_chip, nq, nkv, hd):
         assert name in txt, name
 
 
+def test_mla_flash_attention_compiles_for_v5e(one_chip):
+    """Moonlight's MLA: q/k heads 128 + 64 rotary wide, v heads 128."""
+    q = jax.ShapeDtypeStruct((1, SEQ, 16, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, SEQ, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(ops.flash_attention, q, k, v)
+        return out, vjp(out)
+
+    txt = jax.jit(fwd_bwd).lower(q, q, v).compile().as_text()
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in txt, name
+
+
+def test_grouped_matmul_compiles_for_v5e(one_chip):
+    """The held experts' grouped matmuls at Moonlight's widths: 8 of 64
+    experts, 2,048 tokens x top-6 picks, d 2,048 -> 1,408 -> 2,048."""
+    rows = jax.ShapeDtypeStruct((SEQ * 6, 2048), jnp.bfloat16,
+                                sharding=one_chip)
+    w_up = jax.ShapeDtypeStruct((8, 2048, 1408), jnp.bfloat16,
+                                sharding=one_chip)
+    w_down = jax.ShapeDtypeStruct((8, 1408, 2048), jnp.bfloat16,
+                                  sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(x, wu, wd, gs):
+        def f(x, wu, wd):
+            h = ops.grouped_matmul(x, wu, gs, 0)
+            return ops.grouped_matmul(h, wd, gs, 0)
+        out, vjp = jax.vjp(f, x, wu, wd)
+        return out, vjp(out)
+
+    txt = jax.jit(fwd_bwd).lower(rows, w_up, w_down, sizes).compile() \
+        .as_text()
+    names = {line.split(" = ")[0].strip() for line in txt.splitlines()
+             if "tpu_custom_call" in line and " = " in line}
+    assert any("tgmm" in n for n in names), names
+    assert any("gmm" in n and "tgmm" not in n for n in names), names
+
+
 def test_full_width_stage_fits_v5e_hbm(one_chip):
     """The last executor stage of qwen1.5-0.5b (6 layers, the 151,936-row
     tied unembedding and its fp32 logits) at micro-batch 1 x seq 1024,
